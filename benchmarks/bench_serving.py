@@ -500,8 +500,10 @@ def main():
     args = ap.parse_args()
 
     from repro.configs.registry import get_config
+    from repro.launch.cache import enable_compile_cache
     from repro.models import lm
 
+    enable_compile_cache()
     cfg = get_config(args.arch).reduced()
     params = lm.init_params(cfg, jax.random.PRNGKey(args.seed))
 

@@ -99,9 +99,12 @@ def restore_pytree(template, directory: str, step: int | None = None,
     else:
         spec_leaves = [None] * len(leaves)
     for arr, tmpl, spec in zip(leaves, tmpl_leaves, spec_leaves):
-        x = jnp.asarray(arr, dtype=tmpl.dtype)
         if ms is not None and spec is not None:
-            x = jax.device_put(x, NamedSharding(ms.mesh, spec))
+            # host -> shards directly: never the whole leaf on one device
+            x = jax.device_put(np.asarray(arr, dtype=tmpl.dtype),
+                               NamedSharding(ms.mesh, spec))
+        else:
+            x = jnp.asarray(arr, dtype=tmpl.dtype)
         out.append(x)
     return jax.tree_util.tree_unflatten(treedef, out), meta
 

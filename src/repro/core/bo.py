@@ -33,7 +33,9 @@ def expected_improvement(mu, sigma, best):
             out[i] = max(best - m, 0.0)
             continue
         z = (best - m) / s
-        out[i] = (best - m) * _Phi(z) + s * _phi(z)
+        # for z << 0 the two terms cancel to a rounding-size negative;
+        # EI is non-negative by definition
+        out[i] = max((best - m) * _Phi(z) + s * _phi(z), 0.0)
     return out
 
 

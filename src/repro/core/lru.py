@@ -85,14 +85,10 @@ class LRUCache:
 
 
 def aot_compile(fn, *example_args):
-    """jax.jit + ahead-of-time lower/compile, falling back to
-    compile-on-first-call when lowering fails (donated-arg or abstract-shape
-    edge cases).  Shared by the training loop and the serving engine so the
-    compile cost lands inside the measured reconfiguration window instead of
-    the next iteration's time."""
+    """jax.jit + ahead-of-time lower/compile.  Shared by the training loop
+    and the serving engine so the compile cost lands inside the measured
+    reconfiguration window instead of the next iteration's time.  Errors
+    propagate: a program the compiler refuses fails where it is built,
+    never later as a silent compile-on-first-call."""
     import jax
-    jitted = jax.jit(fn)
-    try:
-        return jitted.lower(*example_args).compile()
-    except Exception:
-        return jitted
+    return jax.jit(fn).lower(*example_args).compile()

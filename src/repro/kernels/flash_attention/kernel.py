@@ -18,11 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases;
-# resolve whichever this version provides.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -132,7 +127,7 @@ def flash_attention(q, k, v, q_positions=None, kv_positions=None, *,
             pltpu.VMEM((block_q, 128), jnp.float32),   # l
             pltpu.VMEM((block_q, hd), jnp.float32),    # acc
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qpos, kpos, qf, kf, vf)

@@ -20,11 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases;
-# resolve whichever this version provides.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, hout_ref, h_ref,
                  *, chunk: int, n_chunks: int):
@@ -94,7 +89,7 @@ def selective_scan(x, dt, Bm, Cm, A, *, chunk: int = 64,
             jax.ShapeDtypeStruct((B, N, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, block_d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, Bm, Cm, At)

@@ -22,8 +22,13 @@ Blocks entirely in the future of every query are *skipped* via pl.when
 (the gather path computes-then-masks them).
 
 GQA is handled in the K/V index maps: query head h reads kv head h // G,
-so the kv pool is never expanded to H heads.  The ``block_size`` knob of
-the serving pool is the kernel's kv tile size — the tuner picks the tile.
+so the kv pool is never expanded to H heads.  The pool is head-major,
+(NB, K, bs, hd), so one step's KV tile is a whole (bs, hd) slab of one
+head: its last two dims are the array's own, which the TPU lowering
+accepts for any pool dtype and block size (a (bs, 1, hd) slice of a
+token-major (NB, bs, K, hd) pool is refused when K > 1).  The
+``block_size`` knob of the serving pool is the kernel's kv tile size — the
+tuner picks the tile.
 """
 from __future__ import annotations
 
@@ -33,11 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams across releases;
-# resolve whichever this version provides.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
 NEG_INF = -1e30
 
@@ -60,8 +60,8 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     def _body():
         q = q_ref[0].astype(jnp.float32)               # (S, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # (bs, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)             # (bs, hd)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale                               # (S, bs)
@@ -91,8 +91,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, pos, *,
                     ctx_cols: int = 0, interpret: bool = False):
     """Attention of S query tokens per request over a paged KV cache.
 
-    q: (B, S, H, hd); k_pool, v_pool: (NB, bs, K, hd) physical blocks with
-    H % K == 0; block_tables: (B, MB) int32 physical block per logical
+    q: (B, S, H, hd); k_pool, v_pool: (NB, K, bs, hd) head-major physical
+    blocks with H % K == 0; block_tables: (B, MB) int32 physical block per logical
     block; pos: (B,) int32 logical position of the *first* query token
     (query j of request b sits at pos[b] + j — S=1 is single-token decode,
     S>1 is chunked decode against a prior cache).  ``ctx_cols`` (static;
@@ -104,7 +104,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, pos, *,
     dense cache and running full-softmax attention (ref.py).
     """
     B, S, H, hd = q.shape
-    NB, bs, K, _ = k_pool.shape
+    NB, K, bs, _ = k_pool.shape
     MB = block_tables.shape[1]
     n_vis = min(ctx_cols, MB) if ctx_cols else MB
     G = H // K
@@ -119,7 +119,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, pos, *,
     def kv_index(bh, kb, tables_ref, pos_ref):
         b = bh // H
         h = bh % H
-        return (tables_ref[b, kb], 0, h // G, 0)
+        return (tables_ref[b, kb], h // G, 0, 0)
 
     kernel = functools.partial(
         _paged_kernel, sm_scale=hd ** -0.5, bs=bs, n_kb=n_vis, S=S, H=H)
@@ -129,8 +129,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, pos, *,
         grid=(B * H, n_vis),
         in_specs=[
             pl.BlockSpec((1, S, hd), q_index),
-            pl.BlockSpec((1, bs, 1, hd), kv_index),
-            pl.BlockSpec((1, bs, 1, hd), kv_index),
+            pl.BlockSpec((None, None, bs, hd), kv_index),
+            pl.BlockSpec((None, None, bs, hd), kv_index),
         ],
         out_specs=pl.BlockSpec((1, S, hd), q_index),
         scratch_shapes=[
@@ -143,7 +143,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables, pos, qf, k_pool, v_pool)

@@ -1,7 +1,7 @@
 """jit'd public wrapper for the paged-attention kernel.
 
 Consumes the PagedKVPool layout directly: physical KV blocks
-(NB, bs, K, hd) + per-request block tables (B, MB) + first-query
+(NB, K, bs, hd) + per-request block tables (B, MB) + first-query
 positions (B,).  The pool's int8-quantized KV layout (blockwise
 fake-quant: values are stored dequantized in the pool dtype, see
 ServingEngine._quant_exec) needs no special handling — the kernel reads

@@ -9,22 +9,16 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax import sharding as _sharding
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.distributed.sharding import MeshSpec
-
-# jax.sharding.AxisType (explicit-sharding API) only exists in newer jax;
-# older versions default every axis to Auto, so omitting it is equivalent.
-_AxisType = getattr(_sharding, "AxisType", None)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    kw = ({"axis_types": (_AxisType.Auto,) * len(axes)}
-          if _AxisType is not None else {})
-    return jax.make_mesh(shape, axes, **kw)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def production_meshspec(*, multi_pod: bool = False) -> MeshSpec:

@@ -46,8 +46,10 @@ def main():
     from repro.obs import NOP_TRACER, Tracer, write_chrome_trace
     from repro.obs.report import format_attribution, time_attribution
     from repro.ps.lm_job import (DEFAULT_LM_SETTING, LMJob, lm_knob_space)
+    from repro.launch.cache import enable_compile_cache
     from repro.ps.trainer import SelfTuningLoop
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -87,11 +87,21 @@ def chunked_attention(q, k, v, *, causal: bool, q_positions, kv_positions,
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
+def gather_blocks(pool, block_tables):
+    """Head-major block pool (NB, K, bs, hd) + tables (B, MB) -> the dense
+    logical cache (B, MB*bs, K, hd) those table columns address."""
+    B, MB = block_tables.shape
+    _, K, bs, hd = pool.shape
+    g = pool[block_tables]                                  # (B,MB,K,bs,hd)
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, MB * bs, K, hd)
+
+
 def paged_decode_attention(q, k_pool, v_pool, block_tables, *, pos,
                            ctx_cols: int = 0):
     """Attention of S query tokens over a *paged* KV cache, block at a time.
 
-    q: (B, S, H, hd); k_pool, v_pool: (NB, bs, K, hd) physical blocks;
+    q: (B, S, H, hd); k_pool, v_pool: (NB, K, bs, hd) head-major physical
+    blocks;
     block_tables: (B, MB) physical block per logical block; pos: (B,)
     logical position of the first query token (query j sits at pos + j,
     so S=1 is single-token decode and S>1 is multi-token chunked decode,
@@ -115,15 +125,15 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, *, pos,
                                   ctx_cols=ctx_cols)
 
     B, S, H, hd = q.shape
-    NB, bs, K, _ = k_pool.shape
+    bs = k_pool.shape[2]
     MB = block_tables.shape[1]
     w = min(ctx_cols, MB) if ctx_cols else MB   # visible table columns
     bt = block_tables[:, :w]
     scale = hd ** -0.5
     qf = q.astype(jnp.bfloat16)
     q_pos = pos[:, None] + jnp.arange(S)[None, :]           # (B, S)
-    kb = _repeat_kv(k_pool[bt].reshape(B, w * bs, K, hd), H)
-    vb = _repeat_kv(v_pool[bt].reshape(B, w * bs, K, hd), H)
+    kb = _repeat_kv(gather_blocks(k_pool, bt), H)
+    vb = _repeat_kv(gather_blocks(v_pool, bt), H)
     s = jnp.einsum("bqhd,bkhd->bhqk", qf, kb,
                    preferred_element_type=jnp.float32) * scale
     kvp = jnp.arange(w * bs)

@@ -21,7 +21,7 @@ from repro.configs.base import ModelConfig
 from repro.distributed.sharding import MeshSpec, constrain, path_str
 from repro.models import common
 from repro.models.attention import (chunked_attention, decode_attention,
-                                    paged_decode_attention)
+                                    gather_blocks, paged_decode_attention)
 from repro.models.mamba import mamba1_block, mamba2_block
 from repro.models.moe import moe_block
 
@@ -133,6 +133,12 @@ def param_shapes(cfg: ModelConfig):
     return tree
 
 
+# one fused program per leaf: the f32 draw never materializes beside the
+# bf16 result (eagerly, a published-width MLP stack's f32 temporaries alone
+# outgrow a 16 GB chip)
+_dense_init_fused = jax.jit(common.dense_init, static_argnums=(1, 2, 3))
+
+
 def init_params(cfg: ModelConfig, key):
     """Materialize parameters (reduced configs / real runs only)."""
     shapes = param_shapes(cfg)
@@ -143,9 +149,8 @@ def init_params(cfg: ModelConfig, key):
         if len(sds.shape) <= 1:
             flat.append(jnp.zeros(sds.shape, sds.dtype))
         else:
-            flat.append(common.dense_init(
-                k, sds.shape, in_axis=max(0, len(sds.shape) - 2),
-                dtype=sds.dtype))
+            flat.append(_dense_init_fused(
+                k, sds.shape, max(0, len(sds.shape) - 2), sds.dtype))
     params = jax.tree_util.tree_unflatten(treedef, flat)
 
     def fix(path, x):
@@ -180,9 +185,11 @@ def _attn_apply(x, p, cfg: ModelConfig, ms, knobs: ModelKnobs, positions,
 
     Decode caches come in two layouts:
       * dense (B, Smax, K, hd): position p of request b is row (b, p);
-      * paged (NB, bs, K, hd) + ``block_tables`` (B, MB): position p of
-        request b lives at physical (block_tables[b, p // bs], p % bs) —
-        the KV-pool indirection of the serving engine's PagedKVPool.
+      * paged (NB, K, bs, hd) + ``block_tables`` (B, MB): position p of
+        request b lives at physical block block_tables[b, p // bs], row
+        p % bs of every head — the KV-pool indirection of the serving
+        engine's PagedKVPool.  Blocks are head-major so one head's
+        (bs, hd) slab is the paged-attention kernel's tile.
     Both accept S >= 1 new tokens (S > 1 = chunked prefill against a prior
     cache, e.g. a shared prompt prefix)."""
     B, S, D = x.shape
@@ -219,9 +226,9 @@ def _attn_apply(x, p, cfg: ModelConfig, ms, knobs: ModelKnobs, positions,
                                 q_positions=positions, kv_positions=positions,
                                 q_chunk=knobs.q_chunk, k_chunk=knobs.k_chunk)
         new_kv = (k, v)
-    elif block_tables is not None:          # decode: paged (NB, bs, K, hd)
+    elif block_tables is not None:          # decode: paged (NB, K, bs, hd)
         k_cache, v_cache = cache
-        bs = k_cache.shape[1]
+        bs = k_cache.shape[2]
         MB = block_tables.shape[1]
         blk = jnp.take_along_axis(block_tables,
                                   jnp.minimum(positions // bs, MB - 1), axis=1)
@@ -232,12 +239,13 @@ def _attn_apply(x, p, cfg: ModelConfig, ms, knobs: ModelKnobs, positions,
         # land there and are never read.
         blk = jnp.where(positions >= MB * bs, 0, blk)
         off = positions % bs                                # (B, S)
-        k_cache = k_cache.at[blk, off].set(k.astype(k_cache.dtype))
-        v_cache = v_cache.at[blk, off].set(v.astype(v_cache.dtype))
+        # [blk, :, off] indexes (B, S) rows across all heads: (B, S, K, hd)
+        k_cache = k_cache.at[blk, :, off].set(k.astype(k_cache.dtype))
+        v_cache = v_cache.at[blk, :, off].set(v.astype(v_cache.dtype))
         if knobs.attn_impl == "gather":     # pre-kernel path (ablation arm)
-            kg = k_cache[block_tables].reshape(B, MB * bs, K, hd)
-            vg = v_cache[block_tables].reshape(B, MB * bs, K, hd)
-            out = decode_attention(q, kg, vg, pos=pos)
+            out = decode_attention(q, gather_blocks(k_cache, block_tables),
+                                   gather_blocks(v_cache, block_tables),
+                                   pos=pos)
         else:                               # read blocks in place (kernel)
             # host-chosen context bucket: the kernel's kv grid axis spans
             # only the visible table prefix (attn_ctx columns; 0 = all)
@@ -542,7 +550,8 @@ def init_paged_cache_shapes(cfg: ModelConfig, n_blocks: int, block_size: int):
     no sequence axis to page."""
     assert cfg.family in ("dense", "moe", "vlm", "encoder"), cfg.family
     L, K, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
-    sds = jax.ShapeDtypeStruct((L, n_blocks, block_size, K, hd), jnp.bfloat16)
+    # head-major blocks: (bs, hd) per head is the kernel's KV tile
+    sds = jax.ShapeDtypeStruct((L, n_blocks, K, block_size, hd), jnp.bfloat16)
     return {"k": sds, "v": sds}
 
 

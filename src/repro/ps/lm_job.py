@@ -16,7 +16,8 @@ from repro.configs.base import ModelConfig, TrainConfig
 from repro.core.knobs import Knob, KnobSpace
 from repro.core.reconfig import ReconfigPlan
 from repro.data.synthetic import lm_batch_iterator
-from repro.distributed.sharding import MeshSpec, param_specs
+from repro.distributed.sharding import (MeshSpec, param_shardings,
+                                       param_specs)
 from repro.launch.mesh import make_meshspec
 from repro.models import lm
 from repro.optim import make_optimizer
@@ -80,15 +81,25 @@ class LMJob:
 
     # ----------------------------------------------------------------- state
     def init_state(self, setting: dict, seed: int = 0):
-        params = lm.init_params(self.cfg, jax.random.PRNGKey(seed))
-        opt_init, _ = make_optimizer(self.tc)
-        state = {"params": params, "opt": opt_init(params),
-                 "step": jnp.zeros((), jnp.int32)}
-        s = setting.get("staleness", 0)
-        if s > 0:
-            state["grad_queue"] = jax.tree_util.tree_map(
-                lambda p: jnp.zeros((s,) + p.shape, jnp.bfloat16), params)
-        return self._place(state, setting)
+        def make():
+            params = lm.init_params(self.cfg, jax.random.PRNGKey(seed))
+            opt_init, _ = make_optimizer(self.tc)
+            state = {"params": params, "opt": opt_init(params),
+                     "step": jnp.zeros((), jnp.int32)}
+            s = setting.get("staleness", 0)
+            if s > 0:
+                state["grad_queue"] = jax.tree_util.tree_map(
+                    lambda p: jnp.zeros((s,) + p.shape, jnp.bfloat16),
+                    params)
+            return state
+
+        ms = self.meshspec(setting)
+        if ms.n_devices == 1:
+            return make()
+        # built in place under its shardings: a published-width state is
+        # larger than any one device, so it never exists unsharded
+        shapes = jax.eval_shape(make)
+        return jax.jit(make, out_shardings=param_shardings(shapes, ms))()
 
     def _place(self, state, setting):
         ms = self.meshspec(setting)
@@ -96,6 +107,20 @@ class LMJob:
             return state
         specs = param_specs(state, ms)
         return odmr.relocate_now(state, specs, ms)
+
+    def _checkpoint_restore(self, state, setting):
+        """The baseline's round trip: save to disk, restore straight into
+        the new placement (each leaf goes host -> its shards)."""
+        import tempfile
+        from repro.checkpoint import restore_pytree, save_pytree
+        ms = self.meshspec(setting)
+        template = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
+        with tempfile.TemporaryDirectory() as d:
+            save_pytree(state, d, step=0)
+            state, _ = restore_pytree(
+                template, d, step=0, ms=ms if ms.n_devices > 1 else None)
+        return state
 
     # ------------------------------------------------------------------ step
     def step_builder(self, setting: dict):
@@ -111,14 +136,7 @@ class LMJob:
             if plan.method == "odmr":
                 state = self._place(state, plan.new)
             else:                       # baseline: CKP + MDR round trip
-                import tempfile
-                from repro.checkpoint import restore_pytree, save_pytree
-                with tempfile.TemporaryDirectory() as d:
-                    save_pytree(state, d, step=0)
-                    template = jax.tree_util.tree_map(
-                        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
-                    state, _ = restore_pytree(template, d, step=0)
-                state = self._place(state, plan.new)
+                state = self._checkpoint_restore(state, plan.new)
         return state
 
     # ------------------------------------------------------------------ data

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import threading
 import time
+import warnings
 from collections import deque
 from dataclasses import dataclass, field
 
@@ -136,7 +137,11 @@ class ServingEngine:
         # neither stall a tick nor gate a reconfig commit — the engine
         # serves the plain one-token path until the background build folds
         self._spec_warm_pending: set = set()   # keys building (or failed)
-        self._spec_warm_done: list = []        # (key, exec|None, build_s)
+        self._spec_warm_done: list = []        # (key, exec|error, build_s)
+        self._spec_threads: list = []          # their build threads
+        # background builds the compiler refused: counted and warned, never
+        # swallowed — a refused verify executable means speculation is off
+        self.failed_builds = 0
         self.last_reconfig_breakdown = {}  # measured per-kind s, last plan
         self.last_reconfig_scales = {}     # units migrated, last plan
         # staged (zero-downtime) reconfiguration — begin_reconfig stages a
@@ -437,7 +442,8 @@ class ServingEngine:
                     blk = jnp.asarray(
                         self.pool.tables[slot, pos // self.pool.bs])
                     off = jnp.asarray(pos % self.pool.bs)
-                    kv = {k: self.pool.kv[k][:, blk, off]
+                    kv = {k: self.pool.kv[k][:, blk, :, off]
+                          .transpose(1, 0, 2, 3)         # (L, m, K, hd)
                           for k in ("k", "v")}
                     if m < bucket:
                         kv = {k: jnp.pad(v, ((0, 0), (0, bucket - m),
@@ -556,9 +562,9 @@ class ServingEngine:
         thread per key and report not-ready — the tick falls back to the
         plain one-token decode until the build folds, so a spec_k flip
         commits instantly (Type II) and never pays a mid-tick compile.  A
-        failed build leaves its key parked in ``_spec_warm_pending``:
-        speculation stays off for that shape instead of retrying a
-        deterministic compile failure every tick."""
+        failed build is counted in ``failed_builds`` and warned about, and
+        its key stays parked in ``_spec_warm_pending`` so a deterministic
+        compile failure is not retried every tick."""
         key = ("decode", self.attn_impl, cols, s) + self.pool.exec_key()
         if key in self._steps:
             return True
@@ -574,22 +580,42 @@ class ServingEngine:
                 t0 = time.perf_counter()
                 try:
                     ex = build()
-                except Exception:
-                    ex = None
+                except Exception as e:      # reported by _fold_spec_warm
+                    ex = e
                 out.append((key, ex, time.perf_counter() - t0))
 
-            threading.Thread(target=worker, daemon=True).start()
+            th = threading.Thread(target=worker, daemon=True)
+            self._spec_threads = [t for t in self._spec_threads
+                                  if t.is_alive()] + [th]
+            th.start()
         return False
+
+    def join_builds(self):
+        """Wait for the verify builds still running in the background and
+        fold them in, failures counted: before reading ``failed_builds``
+        as final, and before the process exits (a daemon thread killed
+        inside the compiler aborts the process)."""
+        for th in self._spec_threads:
+            th.join()
+        self._spec_threads = []
+        self._fold_spec_warm()
 
     def _fold_spec_warm(self):
         """Absorb finished background spec-executable builds (tick path;
         list.append/pop are atomic under the GIL)."""
         while self._spec_warm_done:
             key, ex, dur = self._spec_warm_done.pop()
-            if ex is not None:
-                self._spec_warm_pending.discard(key)
-                self._steps.absorb(key, ex, dur)
-                self.tr.record("exec.precompile_bg", dur, key=str(key))
+            if isinstance(ex, Exception):
+                self._build_failed(key, ex)
+                continue
+            self._spec_warm_pending.discard(key)
+            self._steps.absorb(key, ex, dur)
+            self.tr.record("exec.precompile_bg", dur, key=str(key))
+
+    def _build_failed(self, key, err: Exception):
+        self.failed_builds += 1
+        warnings.warn(f"background compile of {key} failed: {err!r}",
+                      RuntimeWarning, stacklevel=2)
 
     # ---------------------------------------------------------------- tick
     def step(self, now: float | None = None) -> dict:
@@ -1056,8 +1082,8 @@ class ServingEngine:
             t0 = time.perf_counter()
             try:
                 ex = build()
-            except Exception:
-                ex = None        # commit falls back to a foreground build
+            except Exception as e:   # counted at fold; the commit's own
+                ex = e               # foreground build raises it again
             st["builds"].append((key, ex, time.perf_counter() - t0))
         st["done_building"] = True
 
@@ -1071,7 +1097,9 @@ class ServingEngine:
             key, ex, dur = builds[st["folded"]]
             st["folded"] += 1
             st["bg_precompile_s"] += dur
-            if ex is not None:
+            if isinstance(ex, Exception):
+                self._build_failed(key, ex)
+            else:
                 self._steps.absorb(key, ex, dur)
                 self.tr.record("exec.precompile_bg", dur, key=str(key))
         warm = st["done_building"] and st["folded"] == len(st["builds"])
@@ -1251,6 +1279,7 @@ def serve_loop(engine: ServingEngine, trace, tuner=None, *,
     st0 = engine.spec_ticks
     sh0 = engine.pool.shared_blocks_hit
     cow0 = engine.pool.cow_copies
+    fb0 = engine.failed_builds
     t_start = time.perf_counter()
     reconfigs = []
     reconfig_total_s = 0.0
@@ -1356,6 +1385,7 @@ def serve_loop(engine: ServingEngine, trace, tuner=None, *,
         # state (hit/miss/build-time — Type II swap warmth in one line)
         "pool": engine.pool.snapshot(),
         "exec_cache": engine._steps.stats(),
+        "failed_builds": engine.failed_builds - fb0,
     }
     drafted = engine.spec_drafted - sd0
     stats["speculation"] = {

@@ -479,8 +479,9 @@ class PagedKVPool(StatePool):
         blk = jnp.asarray(self.tables[slot, pos // self.bs])
         off = jnp.asarray(pos % self.bs)
         for k, rows in kv.items():
-            self.kv[k] = self.kv[k].at[:, blk, off].set(
-                rows.astype(self.kv[k].dtype))
+            # [:, blk, :, off] on (L, NB, K, bs, hd) selects (n, L, K, hd)
+            self.kv[k] = self.kv[k].at[:, blk, :, off].set(
+                rows.transpose(1, 0, 2, 3).astype(self.kv[k].dtype))
 
     # --------------------------------------------------------------- decode
     def decode_cache(self) -> dict:
@@ -615,10 +616,11 @@ class PagedKVPool(StatePool):
                 blk = np.asarray(self.tables[ns])[pos // self.bs]
                 off = pos % self.bs
                 for k in new_host:
-                    L, _, obs, K, hd = old_host[k].shape
-                    g = old_host[k][:, bt].reshape(L, self.mb_of(obs) * obs,
-                                                   K, hd)[:, :written]
-                    new_host[k][:, blk, off] = g.astype(new_host[k].dtype)
+                    L, _, K, obs, hd = old_host[k].shape
+                    g = old_host[k][:, bt].transpose(1, 3, 0, 2, 4).reshape(
+                        self.mb_of(obs) * obs, L, K, hd)[:written]
+                    # [:, blk, :, off] selects (written, L, K, hd)
+                    new_host[k][:, blk, :, off] = g.astype(new_host[k].dtype)
             if touched:
                 self.kv = {k: jnp.asarray(v) for k, v in new_host.items()}
         # the budget floor only has to hold while live data is being

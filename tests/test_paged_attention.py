@@ -25,8 +25,8 @@ def _rand(shape, dtype=jnp.float32):
 def _case(B, S, H, K, hd, bs, MB, NB=None, pos=None):
     NB = NB or (B * MB + 3)
     q = _rand((B, S, H, hd))
-    kp = _rand((NB, bs, K, hd))
-    vp = _rand((NB, bs, K, hd))
+    kp = _rand((NB, K, bs, hd))          # head-major pool blocks
+    vp = _rand((NB, K, bs, hd))
     bt = jnp.asarray(RNG.integers(0, NB, (B, MB)), jnp.int32)
     if pos is None:
         pos = RNG.integers(0, MB * bs - S, (B,))
@@ -38,10 +38,10 @@ def _gather_path(q, kp, vp, bt, pos):
     """The pre-kernel serving path verbatim: dense gather + dense decode
     attention (models.lm paged branch with attn_impl="gather")."""
     B, S, H, hd = q.shape
-    NB, bs, K, _ = kp.shape
+    NB, K, bs, _ = kp.shape
     MB = bt.shape[1]
-    kg = kp[bt].reshape(B, MB * bs, K, hd)
-    vg = vp[bt].reshape(B, MB * bs, K, hd)
+    kg = kp[bt].transpose(0, 1, 3, 2, 4).reshape(B, MB * bs, K, hd)
+    vg = vp[bt].transpose(0, 1, 3, 2, 4).reshape(B, MB * bs, K, hd)
     return decode_attention(q, kg, vg, pos=pos)
 
 
@@ -183,13 +183,14 @@ def test_bucket_pad_writes_go_to_trash_block():
     _, nc = lm.decode_step(params, cache, tok, pos, cfg, None,
                            ModelKnobs(attn_impl="paged"))
     for key in ("k", "v"):
-        after = np.asarray(nc[key])
+        after = np.asarray(nc[key])       # (L, NB, K, bs, hd)
         # real rows were written
-        assert not np.allclose(after[:, 8, 4:], before[key][:, 8, 4:])
+        assert not np.allclose(after[:, 8, :, 4:], before[key][:, 8, :, 4:])
         # rows 0..3 of the last live block (logical 24..27) are untouched
-        np.testing.assert_array_equal(after[:, 8, :4], before[key][:, 8, :4])
+        np.testing.assert_array_equal(after[:, 8, :, :4],
+                                      before[key][:, 8, :, :4])
         # the pad rows went to the trash block
-        assert not np.allclose(after[:, 0, :4], before[key][:, 0, :4])
+        assert not np.allclose(after[:, 0, :, :4], before[key][:, 0, :, :4])
 
 
 def test_multi_token_chunked_decode_paged():

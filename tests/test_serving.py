@@ -196,3 +196,39 @@ def test_engine_step_cache_bounded(model):
     serve_loop(engine, reqs)
     assert len(engine._steps) <= 2
     assert len(engine.finished) == 3                # eviction never corrupts
+
+
+def test_aot_compile_raises_compile_errors():
+    """A program the compiler refuses fails where it is built; it never
+    comes back as a jit that would fail (or recompile) at first call."""
+    from repro.core.lru import aot_compile
+    bad = jax.ShapeDtypeStruct((2, 2), jnp.float32)
+    with pytest.raises(TypeError):
+        aot_compile(lambda x: x @ jnp.ones((3, 3)), bad)
+
+
+def test_failed_background_build_is_counted(model):
+    """A speculative-verify executable whose background build fails is
+    counted and warned about; its key stays parked (no rebuild per tick)
+    and the engine keeps serving on the one-token path."""
+    cfg, params = model
+    eng = ServingEngine(params, cfg, _setting(max_batch=2, spec_k=2.0),
+                        max_seq=48)
+
+    def refusing_build(cols, s):
+        def build():
+            raise RuntimeError("compiler refused the verify step")
+        return ("refused", cols, s), build
+
+    eng._spec_build_from_shapes = refusing_build
+    cols = eng._ctx_cols(15)        # the one bucket the traffic below uses
+    assert not eng._spec_exec_ready(cols, 3)       # kicks the worker
+    with pytest.warns(RuntimeWarning, match="compiler refused"):
+        eng.join_builds()                          # waits, then counts
+    assert eng.failed_builds == 1
+    assert not eng._spec_exec_ready(cols, 3)       # parked: no new thread
+    assert not eng._spec_warm_done and eng.failed_builds == 1
+    reqs = _requests(cfg, [5, 9], max_new=4)
+    stats = serve_loop(eng, reqs)
+    assert stats["completed"] == 2 and stats["failed_builds"] == 0
+    assert all(len(r.tokens_out) == 4 for r in reqs)

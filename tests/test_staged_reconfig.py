@@ -68,8 +68,9 @@ def _logical_kv(engine):
         bt = np.asarray(pool.tables[s])
         rows = {}
         for k, v in pool.kv.items():
-            a = np.asarray(v)                    # (L, nb, bs, K, hd)
-            g = a[:, bt].reshape(a.shape[0], -1, a.shape[3], a.shape[4])
+            a = np.asarray(v)                    # (L, nb, K, bs, hd)
+            g = a[:, bt].transpose(0, 1, 3, 2, 4).reshape(
+                a.shape[0], -1, a.shape[2], a.shape[4])
             rows[k] = np.asarray(g[:, :written], np.float32)
         out[s] = rows
     return out
@@ -137,8 +138,8 @@ def test_background_migration_preserves_logical_kv(dense_model):
         bt = np.asarray(shadow.tables[shadow_map[s]])
         for k in rows:
             a = np.asarray(shadow.kv[k])
-            g = a[:, bt].reshape(a.shape[0], -1,
-                                 a.shape[3], a.shape[4])
+            g = a[:, bt].transpose(0, 1, 3, 2, 4).reshape(
+                a.shape[0], -1, a.shape[2], a.shape[4])
             np.testing.assert_array_equal(
                 rows[k], np.asarray(g[:, :rows[k].shape[1]], np.float32))
     # prefix-cache keys survive the migration (same block geometry)
