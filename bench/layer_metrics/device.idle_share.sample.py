@@ -1,0 +1,12 @@
+"""Decode step: share of the engine steps' host time (the ``bench.tick``
+annotations of the traced seconds, as ``device.idle_share``) in which the
+chip sat idle inside a ``serve.sample`` span: the argmax, its read to the
+host and the per-slot loop after each decode, in %."""
+from bench import span_clock
+
+
+def read(run):
+    split = span_clock.idle_split(run)
+    if split is None or split["total"] <= 0:
+        return None
+    return 100.0 * split["sample"] / split["total"]

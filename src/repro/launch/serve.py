@@ -26,8 +26,7 @@ import jax
 
 def _engine_main(args, cfg, params):
     from repro.core.tuner import TunerConfig, TuningManager
-    from repro.obs import (MetricsRegistry, Tracer, write_audit_jsonl,
-                           write_chrome_trace)
+    from repro.obs import Tracer, write_audit_jsonl, write_chrome_trace
     from repro.obs.report import format_attribution, time_attribution
     from repro.serving import (DEFAULT_SERVING_SETTING,
                                SERVING_RELAYOUT_KNOBS, ServingEngine,
@@ -96,7 +95,7 @@ def _engine_main(args, cfg, params):
     tracer = None
     if args.trace:
         tracer = Tracer()
-        engine.set_tracer(tracer, MetricsRegistry(enabled=True))
+        engine.set_tracer(tracer)
     tuner = None
     if args.selftune:
         tuner = TuningManager(

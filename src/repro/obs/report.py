@@ -24,6 +24,9 @@ CATEGORY = {
     "serve.chunk_prefill": "prefill",
     "serve.quant": "prefill",
     "serve.decode": "decode",
+    "serve.sample": "decode",          # the step's host side: argmax read,
+                                       # slot loop, completions
+    "pool.write_kv": "prefill",        # nests in serve.prefill
     "decode.draft": "draft",           # host-side proposal cost: must stay
                                        # a sliver of decode or spec_k loses
     "decode.verify": "decode",         # the verify step IS the decode step

@@ -13,6 +13,12 @@ rejects unregistered names, and ``tests/test_docs.py`` fails CI when a
 registered name has no row in ``docs/OBSERVABILITY.md`` — the taxonomy
 cannot silently drift from its documentation.
 
+An enabled tracer also opens a ``jax.profiler.TraceAnnotation`` of the
+span's name around each span, so under a ``jax.profiler`` trace the spans
+sit in the profiler's host plane, on the same clock as the device's
+operations, nested as they were opened.  ``record()`` events, measured on
+another thread, get none.
+
 Disabled tracing must cost nothing on the hot path: ``Tracer(enabled=
 False)`` (and the shared ``NOP_TRACER``) returns one preallocated no-op
 context manager from every ``span()`` call — no object allocation, no
@@ -21,6 +27,8 @@ clock read, no branch beyond the method dispatch.
 from __future__ import annotations
 
 import time
+
+from jax.profiler import TraceAnnotation
 
 # span name -> one-line description.  docs/OBSERVABILITY.md carries the
 # same table (with the attribution category from repro.obs.report);
@@ -33,6 +41,11 @@ SPAN_NAMES = {
                            "blocks (multi-token paged decode)",
     "serve.quant": "int8 re-quantization of freshly written KV rows",
     "serve.decode": "batched decode step: all live slots advance one token",
+    "serve.sample": "after a decode or verify step: adopt the new cache, "
+                    "read the argmax to the host, advance tokens and "
+                    "positions, complete finished requests",
+    "pool.write_kv": "full-prompt prefill's dense KV sliced and scattered "
+                     "into the slot's pool blocks",
     "decode.draft": "drafter proposes spec_k tokens per live slot "
                     "(host-side n-gram lookup or truncated-layer forward)",
     "decode.verify": "speculative verify: ONE batched S=spec_k+1 paged "
@@ -71,7 +84,7 @@ _NOP_SPAN = _NopSpan()
 
 
 class _Span:
-    __slots__ = ("tr", "name", "args", "t_start", "child_s")
+    __slots__ = ("tr", "name", "args", "t_start", "child_s", "ann")
 
     def __init__(self, tr, name, args):
         self.tr = tr
@@ -81,11 +94,14 @@ class _Span:
     def __enter__(self):
         self.child_s = 0.0
         self.tr._stack.append(self)
+        self.ann = TraceAnnotation(self.name)
+        self.ann.__enter__()
         self.t_start = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self.ann.__exit__(None, None, None)
         dur = t1 - self.t_start
         tr = self.tr
         tr._stack.pop()
@@ -129,6 +145,12 @@ class Tracer:
             f"span {name!r} is not in repro.obs.trace.SPAN_NAMES — " \
             f"register it (and its docs/OBSERVABILITY.md row) first"
         return _Span(self, name, args)
+
+    def tag(self, **args):
+        """Add ``args`` to the innermost open span (counts known only
+        part-way through it); nothing when none is open."""
+        if self.enabled and self._stack:
+            self._stack[-1].args.update(args)
 
     def record(self, name: str, dur_s: float, **args):
         """Append a pre-measured span-shaped event without touching the

@@ -45,7 +45,6 @@ from repro.core.lru import LRUCache, aot_compile
 from repro.core.reconfig import (ReconfigPlan, classify as rc_classify,
                                  plan as rc_plan)
 from repro.kernels.quant import dequantize_ref, quantize_ref
-from repro.obs.metrics import NULL_METRICS
 from repro.obs.trace import NOP_TRACER
 from repro.models import lm
 from repro.models.lm import ModelKnobs
@@ -60,10 +59,11 @@ class Request:
     prompt: np.ndarray            # (P,) int32 token ids
     max_new: int                  # tokens to generate (>= 1)
     arrival_s: float = 0.0        # virtual arrival time (trace replay)
-    # engine-filled:
+    # engine-filled, on the engine's clock (``ServingEngine._now``):
     submit_s: float | None = None
-    first_token_s: float | None = None
-    done_s: float | None = None
+    admit_s: float | None = None      # its own admission began
+    first_token_s: float | None = None  # first token read to the host
+    done_s: float | None = None       # last token read to the host
     tokens_out: list = field(default_factory=list)
 
     @property
@@ -76,6 +76,21 @@ class Request:
                 else self.first_token_s - self.arrival_s)
 
 
+def decode_fn(cfg, ms, kn: ModelKnobs):
+    """The engine's decode step, one function for every decode executable
+    (its name names the compiled module: ``jit_serve_decode``)."""
+    def serve_decode(params, cache, tok, pos):
+        logits, new_cache = lm.decode_step(params, cache, tok, pos, cfg, ms,
+                                           kn)
+        # pin state dtypes to the pool's (ssm conv windows come back in
+        # compute dtype) so the AOT signature is a fixed point
+        new_cache = jax.tree_util.tree_map(lambda n, o: n.astype(o.dtype),
+                                           new_cache, cache)
+        return logits, new_cache
+
+    return serve_decode
+
+
 class ServingEngine:
     SUPPORTED_FAMILIES = ("dense", "moe", "vlm", "ssm", "hybrid")
     ADMIT_LOOKAHEAD = 4           # queue positions scanned past a head
@@ -84,7 +99,7 @@ class ServingEngine:
     def __init__(self, params, cfg, setting: dict | None = None, *,
                  max_seq: int = 96, ms=None, step_cache_size: int = 24,
                  block_overcommit: float | None = None,
-                 attn_impl: str = "paged", tracer=None, metrics=None):
+                 attn_impl: str = "paged", tracer=None):
         if cfg.family not in self.SUPPORTED_FAMILIES:
             raise NotImplementedError(
                 f"serving engine supports {self.SUPPORTED_FAMILIES}; "
@@ -102,10 +117,9 @@ class ServingEngine:
         self.setting.update(setting or {})
         if block_overcommit is not None:    # explicit override of the knob
             self.setting["block_overcommit"] = block_overcommit
-        # observability: nested spans on the hot paths + counters/gauges
-        # (both default to the shared zero-overhead no-op instruments)
+        # observability: nested spans on the hot paths (default: the
+        # shared zero-overhead no-op tracer)
         self.tr = tracer or NOP_TRACER
-        self.metrics = metrics or NULL_METRICS
         # compiled executables, bounded-LRU (same policy as the trainer):
         # decode per (pool layout, context bucket), prefill per (bucket,
         # k_chunk), chunked shared-prefix prefill per (bucket, pool layout)
@@ -115,6 +129,7 @@ class ServingEngine:
         self.pool = make_state_pool(cfg, self.setting, max_seq, ms)
         self._reset_slots()
         self.clock = 0.0              # driver-supplied wall time
+        self._step_t0 = time.perf_counter()   # host clock at step start
         self._admit_acc = 0.0         # fractional admit_budget carry
         # accounting (invariants are tested against these)
         self.submitted: list[int] = []
@@ -181,15 +196,18 @@ class ServingEngine:
         return bool(self.queue) or self.n_active > 0
 
     # ----------------------------------------------------------- lifecycle
-    def set_tracer(self, tracer, metrics=None):
-        """Attach (or, with NOP_TRACER, detach) observability sinks.  The
-        executable cache shares the tracer so compile time is attributed
-        wherever it actually fires — inside a reconfiguration window when
-        warmed, inside a tick when a cold path slips through."""
+    def set_tracer(self, tracer):
+        """Attach (or, with NOP_TRACER, detach) a tracer.  The executable
+        cache shares it so compile time is attributed wherever it
+        actually fires — inside a reconfiguration window when warmed,
+        inside a tick when a cold path slips through."""
         self.tr = tracer
         self._steps.tracer = tracer
-        if metrics is not None:
-            self.metrics = metrics
+
+    def _now(self) -> float:
+        """The engine's clock: the step's ``now`` plus the host time
+        elapsed in the step so far (request stamps)."""
+        return self.clock + (time.perf_counter() - self._step_t0)
 
     def submit(self, req: Request, now: float | None = None):
         if req.max_new < 1:
@@ -237,28 +255,9 @@ class ServingEngine:
         the classic decode step; s = spec_k + 1 is the speculative verify
         step — one batched multi-token paged decode over draft tokens)."""
         key = ("decode", self.attn_impl, ctx_cols, s) + self.pool.exec_key()
-
-        def build():
-            cfg, ms = self.cfg, self.ms
-            kn = ModelKnobs(attn_impl=self.attn_impl, attn_ctx=ctx_cols)
-
-            def f(params, cache, tok, pos):
-                logits, new_cache = lm.decode_step(params, cache, tok, pos,
-                                                   cfg, ms, kn)
-                # pin state dtypes to the pool's (ssm conv windows come back
-                # in compute dtype) so the AOT signature is a fixed point
-                new_cache = jax.tree_util.tree_map(
-                    lambda n, o: n.astype(o.dtype), new_cache, cache)
-                return logits, new_cache
-
-            # AOT: compile inside the reconfig window, not mid-tick
-            n = self.pool.n_slots
-            cache = self.pool.decode_cache()
-            tok = jax.ShapeDtypeStruct((n, s), jnp.int32)
-            pos = jax.ShapeDtypeStruct((n,), jnp.int32)
-            return aot_compile(f, self.params, cache, tok, pos)
-
-        return self._steps.get_or_create(key, build)
+        # AOT: compile inside the reconfig window, not mid-tick
+        return self._steps.get_or_create(
+            key, lambda: self._decode_spec(ctx_cols, s)[1]())
 
     def _target_geometry(self, setting: dict) -> dict:
         """The canonical paged-pool geometry ``make_state_pool(setting)``
@@ -273,38 +272,41 @@ class ServingEngine:
                 "nb": n_slots * mb + 1, "dtype": pool_dtype(setting),
                 "cache_dtype": setting.get("cache_dtype")}
 
-    def _decode_build_spec(self, cols: int, geom: dict, s: int = 1):
-        """(LRU key, build fn) for the decode executable of a *future*
-        paged-pool geometry.  The build closes over shapes only (operands
-        are ShapeDtypeStructs), never the live pool — which is what makes
-        it safe to run on the async precompile thread while the tick path
-        keeps decoding.  The key mirrors _decode_exec exactly, including
-        the query width ``s`` (speculative-verify executables are staged
-        the same way single-token ones are)."""
-        key = ("decode", self.attn_impl, cols, s,
-               "paged", geom["n_slots"], geom["nb"], geom["bs"],
-               geom["cache_dtype"])
-        cfg, ms, params = self.cfg, self.ms, self.params
-        kn = ModelKnobs(attn_impl=self.attn_impl, attn_ctx=cols)
-
-        def build():
-            def f(params, cache, tok, pos):
-                logits, new_cache = lm.decode_step(params, cache, tok, pos,
-                                                   cfg, ms, kn)
-                new_cache = jax.tree_util.tree_map(
-                    lambda n, o: n.astype(o.dtype), new_cache, cache)
-                return logits, new_cache
-
-            shapes = lm.init_paged_cache_shapes(cfg, geom["nb"], geom["bs"])
+    def _decode_spec(self, cols: int, s: int = 1, geom: dict | None = None):
+        """(LRU key, build fn) of the decode executable for the live pool
+        or, given ``geom``, for a *future* paged-pool geometry.  The build
+        closes over shapes only (ShapeDtypeStructs, snapshotted here on
+        the caller's thread), never the live pool — which is what makes it
+        safe to run on a background thread while the tick path keeps
+        decoding.  Every decode path compiles ``serve_decode`` under a
+        key of the same form, so a staged or background build is the
+        executable the tick path would have built."""
+        if geom is None:
+            pool_key = self.pool.exec_key()
+            n = self.pool.n_slots
+            # no sharding: lowered as the pool's uncommitted arrays are, so
+            # the step's outputs stay uncommitted too and the eager ops of
+            # admission (write_kv's scatter) hit what warm-up compiled
+            cache = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                self.pool.decode_cache())
+        else:
+            pool_key = ("paged", geom["n_slots"], geom["nb"], geom["bs"],
+                        geom["cache_dtype"])
+            n = geom["n_slots"]
+            shapes = lm.init_paged_cache_shapes(self.cfg, geom["nb"],
+                                                geom["bs"])
             cache = {k: jax.ShapeDtypeStruct(sh.shape, geom["dtype"])
                      for k, sh in shapes.items()}
-            cache["block_tables"] = jax.ShapeDtypeStruct(
-                (geom["n_slots"], geom["mb"]), jnp.int32)
-            tok = jax.ShapeDtypeStruct((geom["n_slots"], s), jnp.int32)
-            pos = jax.ShapeDtypeStruct((geom["n_slots"],), jnp.int32)
-            return aot_compile(f, params, cache, tok, pos)
-
-        return key, build
+            cache["block_tables"] = jax.ShapeDtypeStruct((n, geom["mb"]),
+                                                         jnp.int32)
+        key = ("decode", self.attn_impl, cols, s) + pool_key
+        fn = decode_fn(self.cfg, self.ms,
+                       ModelKnobs(attn_impl=self.attn_impl, attn_ctx=cols))
+        params = self.params
+        tok = jax.ShapeDtypeStruct((n, s), jnp.int32)
+        pos = jax.ShapeDtypeStruct((n,), jnp.int32)
+        return key, lambda: aot_compile(fn, params, cache, tok, pos)
 
     def _prefill_exec(self, bucket: int):
         key = ("prefill", bucket, self.setting["k_chunk"])
@@ -313,7 +315,7 @@ class ServingEngine:
             cfg, ms = self.cfg, self.ms
             kn = ModelKnobs(k_chunk=self.setting["k_chunk"])
 
-            def f(params, tokens, last_idx):
+            def serve_prefill(params, tokens, last_idx):
                 # valid_len: SSM families must not fold right-pad tokens
                 # into the recurrent state (attention ignores it)
                 hidden, _, cache = lm.forward(params, {"tokens": tokens},
@@ -325,7 +327,7 @@ class ServingEngine:
 
             tk = jax.ShapeDtypeStruct((1, bucket), jnp.int32)
             ix = jax.ShapeDtypeStruct((), jnp.int32)
-            return aot_compile(f, self.params, tk, ix)
+            return aot_compile(serve_prefill, self.params, tk, ix)
 
         return self._steps.get_or_create(key, build)
 
@@ -344,7 +346,7 @@ class ServingEngine:
             cfg, ms = self.cfg, self.ms
             kn = ModelKnobs(attn_impl=self.attn_impl)
 
-            def f(params, cache, tokens, start, last_idx):
+            def serve_chunk_prefill(params, cache, tokens, start, last_idx):
                 # project only the last real suffix position to logits —
                 # a full (bucket, vocab) projection would cost bucket x
                 # the FLOPs for one usable row (same trick as _prefill_exec)
@@ -362,7 +364,8 @@ class ServingEngine:
             tk = jax.ShapeDtypeStruct((1, bucket), jnp.int32)
             st = jax.ShapeDtypeStruct((1,), jnp.int32)
             ix = jax.ShapeDtypeStruct((), jnp.int32)
-            return aot_compile(f, self.params, cache, tk, st, ix)
+            return aot_compile(serve_chunk_prefill, self.params, cache, tk,
+                               st, ix)
 
         return self._steps.get_or_create(key, build)
 
@@ -381,13 +384,13 @@ class ServingEngine:
         def build():
             block = max(self.cfg.n_kv_heads * self.cfg.hd, 1)
 
-            def f(kv):                       # (L, n, K, hd)
+            def serve_quant(kv):             # (L, n, K, hd)
                 flat = kv.reshape(-1).astype(jnp.float32)
                 half = jnp.full(flat.shape, 0.5, jnp.float32)  # det. rounding
                 q, scales = quantize_ref(flat, half, block=block)
                 return dequantize_ref(q, scales, block=block).reshape(kv.shape)
 
-            return jax.jit(f)
+            return jax.jit(serve_quant)
 
         return self._steps.get_or_create(key, build)
 
@@ -396,10 +399,13 @@ class ServingEngine:
             return self._admit(req)
 
     def _admit(self, req: Request) -> bool:
+        t_admit = self._now()
         res = self.pool.try_admit(req.prompt, req.max_new)
         if res is None:
             return False
         slot, shared = res
+        req.admit_s = t_admit
+        self.tr.tag(shared=shared)
         P = len(req.prompt)
         if shared > 0:
             # shared-prefix fast path: prefill only the suffix as one
@@ -464,21 +470,22 @@ class ServingEngine:
                     self.params, jnp.asarray(padded),
                     jnp.asarray(P - 1, jnp.int32))
                 if self.pool.kind == "paged":
-                    kv = {k: pcache[k][:, 0] for k in ("k", "v")}
-                    if self.setting["quant"] == "int8":
-                        with self.tr.span("serve.quant", bucket=bucket):
-                            kv = {k: self._quant_exec(bucket)(v)
-                                  for k, v in kv.items()}
-                    self.pool.write_kv(slot, {k: v[:, :P]
-                                              for k, v in kv.items()},
-                                       start=0)
+                    with self.tr.span("pool.write_kv", tokens=P):
+                        kv = {k: pcache[k][:, 0] for k in ("k", "v")}
+                        if self.setting["quant"] == "int8":
+                            with self.tr.span("serve.quant", bucket=bucket):
+                                kv = {k: self._quant_exec(bucket)(v)
+                                      for k, v in kv.items()}
+                        self.pool.write_kv(slot, {k: v[:, :P]
+                                                  for k, v in kv.items()},
+                                           start=0)
                 else:
                     self.pool.write_prefill(slot, pcache, P)
                 tok = int(jnp.argmax(logits[0]))
             self.prefill_tokens_computed += P
         self.prefill_tokens_total += P
         req.tokens_out = [tok]
-        req.first_token_s = self.clock
+        req.first_token_s = self._now()
         self.total_tokens += 1
         self.slot_req[slot] = req
         self.slot_pos[slot] = P
@@ -489,7 +496,7 @@ class ServingEngine:
 
     def _complete(self, slot: int):
         req = self.slot_req[slot]
-        req.done_s = self.clock
+        req.done_s = self._now()
         self.finished.append(req)
         self.slot_req[slot] = None
         self.slot_pos[slot] = 0       # stale positions must not inflate the
@@ -527,34 +534,6 @@ class ServingEngine:
         self._drafter_seed = int(seed)
         self._drafters = {}
 
-    def _spec_build_from_shapes(self, cols: int, s: int):
-        """(LRU key, build fn) for the *live* pool's S = ``s`` decode
-        executable.  Cache shapes are snapshotted on the caller's thread
-        (ShapeDtypeStructs only), so the returned build closure is safe to
-        run on a background thread while the tick path keeps decoding —
-        the generic-pool analogue of ``_decode_build_spec``."""
-        key = ("decode", self.attn_impl, cols, s) + self.pool.exec_key()
-        cfg, ms, params = self.cfg, self.ms, self.params
-        n = self.pool.n_slots
-        cache = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-            self.pool.decode_cache())
-        kn = ModelKnobs(attn_impl=self.attn_impl, attn_ctx=cols)
-
-        def build():
-            def f(params, cache, tok, pos):
-                logits, new_cache = lm.decode_step(params, cache, tok, pos,
-                                                   cfg, ms, kn)
-                new_cache = jax.tree_util.tree_map(
-                    lambda nw, o: nw.astype(o.dtype), new_cache, cache)
-                return logits, new_cache
-
-            tok = jax.ShapeDtypeStruct((n, s), jnp.int32)
-            pos = jax.ShapeDtypeStruct((n,), jnp.int32)
-            return aot_compile(f, params, cache, tok, pos)
-
-        return key, build
-
     def _spec_exec_ready(self, cols: int, s: int) -> bool:
         """True when the S = ``s`` speculative-verify executable for this
         context bucket is warm.  On a miss: build inline when
@@ -573,7 +552,7 @@ class ServingEngine:
             return True
         if key not in self._spec_warm_pending:
             self._spec_warm_pending.add(key)
-            _, build = self._spec_build_from_shapes(cols, s)
+            _, build = self._decode_spec(cols, s)
             out = self._spec_warm_done
 
             def worker():
@@ -619,10 +598,12 @@ class ServingEngine:
 
     # ---------------------------------------------------------------- tick
     def step(self, now: float | None = None) -> dict:
-        """One scheduling quantum.  Returns tick metrics for the driver."""
-        if now is not None:
-            self.clock = now
-        with self.tr.span("serve.tick"):
+        """One scheduling quantum.  Returns tick metrics for the driver.
+        ``now``: the caller's wall time; without it the engine's clock
+        runs on from the previous step by host time."""
+        self.clock = self._now() if now is None else now
+        self._step_t0 = time.perf_counter()
+        with self.tr.span("serve.tick", queued=len(self.queue)):
             return self._tick()
 
     def _tick(self) -> dict:
@@ -632,6 +613,7 @@ class ServingEngine:
 
         # admission: fill an idle engine greedily; while decodes run, the
         # continuous admit_budget knob meters prefills per quantum
+        admitted = refused = 0
         had_decodes = self.n_active > 0
         if had_decodes:
             ab = float(self.setting.get("admit_budget", 1.0))
@@ -643,18 +625,22 @@ class ServingEngine:
             budget = self._max_batch_cap()
         while (self.queue and budget > 0
                and self.n_active < self._max_batch_cap()):
-            admitted = False
+            ok = False
             # block-aware lookahead: a long prompt whose blocks don't fit
             # yet must not strand free slots for the small requests behind it
             for i in range(min(len(self.queue), self.ADMIT_LOOKAHEAD)):
                 if self._try_admit(self.queue[i]):
                     del self.queue[i]
-                    admitted = True
+                    ok = True
                     break
-            if not admitted:
+                refused += 1
+            if not ok:
                 break
+            admitted += 1
             tokens += 1
             budget -= 1
+        self.tr.tag(admitted=admitted, admit_refused=refused,
+                    active=self.n_active)
 
         # decode: advance every live slot.  With spec_k == 0 each slot
         # moves one token per quantum; with spec_k > 0 the drafter proposes
@@ -690,20 +676,21 @@ class ServingEngine:
                     jax.block_until_ready(logits)
                     self.decode_time_s += time.perf_counter() - t_dec
                     self.decode_tokens += len(active)
-                self.pool.set_cache(new_cache)
-                nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
-                                 np.int32)
-                for slot, req in enumerate(self.slot_req):
-                    if req is None:
-                        continue
-                    self.slot_pos[slot] += 1
-                    self.slot_tok[slot] = nxt[slot]
-                    req.tokens_out.append(int(nxt[slot]))
-                    tokens += 1
-                    self.total_tokens += 1
-                    if (len(req.tokens_out) >= req.max_new
-                            or self.slot_pos[slot] >= self.max_seq - 1):
-                        self._complete(slot)
+                with self.tr.span("serve.sample", batch=len(active)):
+                    self.pool.set_cache(new_cache)
+                    nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
+                                     np.int32)
+                    for slot, req in enumerate(self.slot_req):
+                        if req is None:
+                            continue
+                        self.slot_pos[slot] += 1
+                        self.slot_tok[slot] = nxt[slot]
+                        req.tokens_out.append(int(nxt[slot]))
+                        tokens += 1
+                        self.total_tokens += 1
+                        if (len(req.tokens_out) >= req.max_new
+                                or self.slot_pos[slot] >= self.max_seq - 1):
+                            self._complete(slot)
 
         # staged reconfiguration: fold finished precompiles, copy one
         # background-migration batch, commit when warm + fully copied
@@ -721,14 +708,6 @@ class ServingEngine:
             self._relayout_pool()
 
         dt = time.perf_counter() - t0
-        if self.metrics.enabled:
-            self.metrics.histogram("serve.tick_s").observe(dt)
-            self.metrics.gauge("serve.active_slots").set(self.n_active)
-            self.metrics.gauge("serve.queue_depth").set(self.queue_depth)
-            snap = self.pool.snapshot()
-            if "block_utilization" in snap:
-                self.metrics.gauge("pool.block_utilization").set(
-                    snap["block_utilization"])
         return {"dt": dt, "tokens": tokens, "active": self.n_active,
                 "queued": self.queue_depth, "load": self.load,
                 "idle": tokens == 0 and not self.has_work()}
@@ -787,34 +766,37 @@ class ServingEngine:
                 jnp.asarray(pos0))
             jax.block_until_ready(logits)
             self.decode_time_s += time.perf_counter() - t_dec
-        self.pool.set_cache(new_cache)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)   # (n, S)
 
         emitted = 0
         accepted_len = {}                    # slot -> tokens emitted (a+1)
         done = []
-        for s in active:
-            req = self.slot_req[s]
-            p = int(pos0[s])
-            # emission cap: never emit past max_new, and keep the next
-            # write position below max_seq - 1 (the submit-time contract)
-            cap = min(req.max_new - len(req.tokens_out),
-                      self.max_seq - 1 - p)
-            a = 0
-            while a < k and a + 1 < cap and tok[s, a + 1] == nxt[s, a]:
-                a += 1
-            for j in range(a + 1):
-                req.tokens_out.append(int(nxt[s, j]))
-            self.spec_accepted += a
-            emitted += a + 1
-            self.total_tokens += a + 1
-            self.decode_tokens += a + 1
-            accepted_len[s] = a + 1
-            self.slot_pos[s] = p + a + 1
-            self.slot_tok[s] = nxt[s, a]
-            if (len(req.tokens_out) >= req.max_new
-                    or self.slot_pos[s] >= self.max_seq - 1):
-                done.append(s)
+        with self.tr.span("serve.sample", batch=len(active)):
+            self.pool.set_cache(new_cache)
+            nxt = np.asarray(jnp.argmax(logits, axis=-1),
+                             np.int32)                        # (n, S)
+            for s in active:
+                req = self.slot_req[s]
+                p = int(pos0[s])
+                # emission cap: never emit past max_new, and keep the
+                # next write position below max_seq - 1 (the submit-time
+                # contract)
+                cap = min(req.max_new - len(req.tokens_out),
+                          self.max_seq - 1 - p)
+                a = 0
+                while a < k and a + 1 < cap and tok[s, a + 1] == nxt[s, a]:
+                    a += 1
+                for j in range(a + 1):
+                    req.tokens_out.append(int(nxt[s, j]))
+                self.spec_accepted += a
+                emitted += a + 1
+                self.total_tokens += a + 1
+                self.decode_tokens += a + 1
+                accepted_len[s] = a + 1
+                self.slot_pos[s] = p + a + 1
+                self.slot_tok[s] = nxt[s, a]
+                if (len(req.tokens_out) >= req.max_new
+                        or self.slot_pos[s] >= self.max_seq - 1):
+                    done.append(s)
 
         with self.tr.span("decode.rollback", batch=len(active)):
             if self.pool.kind == "paged":
@@ -1056,7 +1038,7 @@ class ServingEngine:
             # flip (_spec_exec_ready) — a spec_k change is Type II and
             # must never hold a plan pending behind cold compiles
             for cols in self._ctx_buckets_for(geom["mb"]):
-                key, build = self._decode_build_spec(cols, geom)
+                key, build = self._decode_spec(cols, geom=geom)
                 if key not in self._steps:
                     specs.append((key, build))
         self._staged = st
@@ -1164,7 +1146,6 @@ class ServingEngine:
                             self.slot_req[new] = old_req[old]
                             self.slot_pos[new] = old_pos[old]
                             self.slot_tok[new] = old_tok[old]
-                        self.metrics.counter("pool.relayouts").inc()
                         committed = True
                     else:
                         self.pool.abort_migration()
@@ -1254,7 +1235,6 @@ class ServingEngine:
                 self.slot_req[new] = old_req[old]
                 self.slot_pos[new] = old_pos[old]
                 self.slot_tok[new] = old_tok[old]
-            self.metrics.counter("pool.relayouts").inc()
 
 
 def serve_loop(engine: ServingEngine, trace, tuner=None, *,

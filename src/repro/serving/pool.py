@@ -84,8 +84,8 @@ class StatePool:
         self.setting = dict(setting)
 
     def snapshot(self) -> dict:
-        """Occupancy/effectiveness counters for the observability layer
-        (gauges per tick, a one-shot summary in serve_loop stats)."""
+        """Occupancy/effectiveness counters: a one-shot summary in
+        serve_loop stats."""
         return {"kind": self.kind, "n_slots": self.n_slots,
                 "live_slots": self.n_active,
                 "shared_blocks_hit": self.shared_blocks_hit,

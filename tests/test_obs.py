@@ -1,8 +1,11 @@
-"""Observability invariants: span nesting/self-time accounting, the
-time-attribution panel summing to ~1.0, Chrome-trace export round-trip,
-audit calibration math, and the no-op tracer staying under 5% of a real
-200-step serve_loop's wall-clock."""
+"""Observability invariants: span nesting/self-time accounting, spans
+named in a jax.profiler trace, the time-attribution panel summing to
+~1.0, Chrome-trace export round-trip, audit calibration math, and the
+no-op tracer staying under 5% of a real 200-step serve_loop's
+wall-clock."""
+import glob
 import json
+import os
 import time
 
 import jax
@@ -81,6 +84,60 @@ def test_noop_span_is_shared_and_records_nothing():
     with s1:
         pass
     assert tr.events == [] and tr._stack == []
+
+
+def test_spans_named_in_profiler_trace(tmp_path):
+    """Under a jax.profiler trace an enabled tracer's spans appear in the
+    host plane by name, nested as they were opened; the no-op tracer's
+    spans leave nothing there."""
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("serve.tick"):
+            with tr.span("serve.admit"):
+                with tr.span("serve.prefill"):
+                    time.sleep(0.002)
+            with tr.span("serve.decode"):
+                time.sleep(0.002)
+        with NOP_TRACER.span("train.step"):
+            time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    ev = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                ev.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns))
+    names = ("serve.tick", "serve.admit", "serve.prefill", "serve.decode")
+    assert all(len(ev.get(n, ())) == 1 for n in names), sorted(ev)
+    assert "train.step" not in ev
+    (tick,), (admit,), (pre,), (dec,) = (ev[n] for n in names)
+
+    def inside(a, b):
+        return b[0] <= a[0] and a[1] <= b[1]
+
+    assert inside(pre, admit) and inside(admit, tick) and inside(dec, tick)
+    assert admit[1] <= dec[0]           # siblings in the order opened
+    # the span list itself is what it was without a profiler
+    assert [e["name"] for e in tr.events] == [
+        "serve.prefill", "serve.admit", "serve.decode", "serve.tick"]
+
+
+def test_tag_adds_args_to_innermost_open_span():
+    tr = Tracer()
+    with tr.span("serve.tick", queued=2):
+        with tr.span("serve.admit"):
+            tr.tag(shared=16)
+        tr.tag(admitted=1)
+    tr.tag(stray=1)                     # no span open: dropped
+    by = {e["name"]: e["args"] for e in tr.events}
+    assert by == {"serve.admit": {"shared": 16},
+                  "serve.tick": {"queued": 2, "admitted": 1}}
+    NOP_TRACER.tag(admitted=1)
+    assert NOP_TRACER.events == []
 
 
 def test_max_events_bounds_memory():

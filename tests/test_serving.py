@@ -1,6 +1,9 @@
 """Serving-engine invariants: request accounting, slot reclamation,
 batched-output correctness vs the unbatched reference decode, online
-re-layout, and the bounded executable cache."""
+re-layout, the bounded executable cache, and the per-step counts and
+request stamps the engine reports."""
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,6 +14,7 @@ from repro.core.lru import LRUCache
 from repro.core.reconfig import plan
 from repro.models import lm
 from repro.models.lm import ModelKnobs
+from repro.obs import Tracer
 from repro.serving import (DEFAULT_SERVING_SETTING, SERVING_RELAYOUT_KNOBS,
                            Request, ServingEngine, serve_loop)
 
@@ -215,12 +219,17 @@ def test_failed_background_build_is_counted(model):
     eng = ServingEngine(params, cfg, _setting(max_batch=2, spec_k=2.0),
                         max_seq=48)
 
-    def refusing_build(cols, s):
+    plain_build = eng._decode_spec
+
+    def refusing_build(cols, s=1, geom=None):
+        if s == 1:                  # the one-token path still builds
+            return plain_build(cols, s, geom)
+
         def build():
             raise RuntimeError("compiler refused the verify step")
         return ("refused", cols, s), build
 
-    eng._spec_build_from_shapes = refusing_build
+    eng._decode_spec = refusing_build
     cols = eng._ctx_cols(15)        # the one bucket the traffic below uses
     assert not eng._spec_exec_ready(cols, 3)       # kicks the worker
     with pytest.warns(RuntimeWarning, match="compiler refused"):
@@ -232,3 +241,88 @@ def test_failed_background_build_is_counted(model):
     stats = serve_loop(eng, reqs)
     assert stats["completed"] == 2 and stats["failed_builds"] == 0
     assert all(len(r.tokens_out) == 4 for r in reqs)
+
+
+def test_tick_counts_and_request_stamps(model):
+    """``serve.tick`` carries the step's queue and admission counts, equal
+    to what the engine did; each request's stamps are ordered, and its
+    first token is stamped after the prefill that made it."""
+    cfg, params = model
+    # 5 usable blocks of 16: two 2-block requests fit, a third is refused
+    # and the 1-block request behind it is admitted past it
+    eng = ServingEngine(params, cfg, _setting(max_batch=3), max_seq=48,
+                        block_overcommit=0.5)
+    tr = Tracer()
+    eng.set_tracer(tr)
+    refused = []
+    try_admit = eng.pool.try_admit
+
+    def counting_try_admit(prompt, max_new):
+        res = try_admit(prompt, max_new)
+        refused[-1] += res is None
+        return res
+
+    eng.pool.try_admit = counting_try_admit
+    reqs = _requests(cfg, [20, 20, 20, 9], max_new=6)
+    t0 = time.perf_counter()
+    for r in reqs:
+        eng.submit(r, now=0.0)
+    steps = []
+    while eng.has_work():
+        refused.append(0)
+        queued = len(eng.queue)
+        now = time.perf_counter() - t0
+        eng.step(now=now)
+        steps.append({"now": now, "queued": queued,
+                      "admitted": queued - len(eng.queue),
+                      "admit_refused": refused[-1]})
+    ticks = [e for e in tr.events if e["name"] == "serve.tick"]
+    decodes = [e for e in tr.events if e["name"] == "serve.decode"]
+    assert len(ticks) == len(steps)
+    assert sum(s["admit_refused"] for s in steps) > 0
+    assert steps[0]["admitted"] == 3 and steps[0]["admit_refused"] == 1
+    for tick, want in zip(ticks, steps):
+        a = tick["args"]
+        for k in ("queued", "admitted", "admit_refused"):
+            assert a[k] == want[k], (k, a, want)
+        # live slots after admission: the batch its decode ran
+        dec = [d for d in decodes
+               if tick["ts"] <= d["ts"] <= tick["ts"] + tick["dur"]]
+        assert a["active"] == (dec[0]["args"]["batch"] if dec else 0)
+    for r in reqs:
+        assert r.submit_s <= r.admit_s <= r.first_token_s <= r.done_s
+        step_now = max(s["now"] for s in steps if s["now"] <= r.admit_s)
+        assert r.first_token_s > step_now      # after its prefill ran
+    admits = [e for e in tr.events if e["name"] == "serve.admit"]
+    assert [e["args"]["shared"] for e in admits
+            if "shared" in e["args"]] == [0] * len(reqs)
+
+
+def test_admission_after_decode_compiles_nothing(model):
+    """An admission of a prompt length warm-up already admitted compiles
+    nothing after decode steps have replaced the pool's arrays: the
+    decode executable's outputs are placed like the arrays it was lowered
+    for, so admission's eager ops hit the warm-up's compilations."""
+    cfg, params = model
+    eng = ServingEngine(params, cfg, _setting(max_batch=2,
+                                              cache_dtype="bf16"),
+                        max_seq=48)
+    for c in sorted({eng._ctx_cols(p) for p in range(9, 40)}):
+        eng._decode_exec(c)
+    eng._prefill_exec(16)
+    for i, plen in enumerate((9, 12)):         # max_new 1: no decode
+        assert eng._admit(_requests(cfg, [plen], max_new=1, seed=i)[0])
+    compiled = []
+
+    def on_event(name, secs, fun_name="?", **_):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiled.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        serve_loop(eng, _requests(cfg, [9], max_new=4, seed=2))
+        n0 = len(compiled)
+        serve_loop(eng, _requests(cfg, [12], max_new=4, seed=3))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert compiled[n0:] == []
