@@ -202,21 +202,31 @@ class Window:
     ticks: list            # trace runs: per-tick host records
 
 
-def drive(engine, specs, traffic: dict, seconds: float, *, record=False,
-          on_tick=None, annotate=None) -> Window:
+def drive(engine, specs, traffic: dict, seconds: float, *, more=(),
+          record=False, on_tick=None, annotate=None) -> Window:
     """Serve ``specs`` on the traffic's schedule: the lead-in, then the
     window.  Open loop: each request is submitted once its due time has
     passed.  Closed loop: ``callers`` requests are in flight; each one
     that completes is replaced by the next, due when the caller learns
-    of the completion.  ``on_tick(now, w0)`` runs between steps (the
-    traced run opens its counters and starts the profiler there)."""
+    of the completion; once every request of ``specs`` has been sent,
+    the next round is taken from ``more`` (``workload.rounds``), so the
+    callers never run dry however fast the program is.  ``on_tick(now,
+    w0)`` runs between steps (the traced run opens its counters and
+    starts the profiler there)."""
     from repro.serving.engine import Request
     open_loop = traffic["loop"] == "open"
     pending = deque(specs)
+    more = iter(more)
     t0 = time.perf_counter()
     w0 = t0 + traffic["lead_in_s"]
     w1 = w0 + seconds
     recs, inflight, ticks = [], [], []
+
+    def take():
+        """The closed loop's next request; None once the supply ends."""
+        if not pending:
+            pending.extend(next(more, ()))
+        return pending.popleft() if pending else None
 
     def submit(spec, due):
         req = Request(rid=spec.idx, prompt=spec.prompt, max_new=spec.max_new,
@@ -231,8 +241,10 @@ def drive(engine, specs, traffic: dict, seconds: float, *, record=False,
         inflight.append(rec)
 
     if not open_loop:
-        for _ in range(min(int(traffic["callers"]), len(pending))):
-            submit(pending.popleft(), t0)
+        for _ in range(int(traffic["callers"])):
+            if (spec := take()) is None:
+                break
+            submit(spec, t0)
     while True:
         now = time.perf_counter()
         if now >= w1:
@@ -265,8 +277,8 @@ def drive(engine, specs, traffic: dict, seconds: float, *, record=False,
                 r.stamps += [te] * (n - len(r.stamps))
             if r.req.done_s is not None:
                 r.done = te
-                if not open_loop and pending:
-                    submit(pending.popleft(), te)
+                if not open_loop and (spec := take()) is not None:
+                    submit(spec, te)
         inflight[:] = [r for r in inflight if r.done is None]
         if record:
             # this tick's decodes: the slots live before it, plus each
@@ -364,7 +376,8 @@ def _run(name, cell, conf, traffic, m, seed, seconds, trace, t_start, dev,
     from repro.obs.trace import Tracer
 
     cfg = model_config(conf)
-    specs = workload.generate(traffic, seed, m["vocab_size"])
+    supply = workload.rounds(traffic, seed, m["vocab_size"])
+    specs = next(supply)                  # round 0 fixes the warmed shapes
     t_init = time.perf_counter()
     params = jax.block_until_ready(make_params(m, seed))
     t_params = time.perf_counter()
@@ -402,7 +415,7 @@ def _run(name, cell, conf, traffic, m, seed, seconds, trace, t_start, dev,
             jax.profiler.start_trace(str(log_dir), profiler_options=opts)
             state["prof"] = time.perf_counter()
 
-    win = drive(engine, specs, traffic, seconds, record=trace,
+    win = drive(engine, specs, traffic, seconds, more=supply, record=trace,
                 on_tick=on_tick,
                 annotate=jax.profiler.TraceAnnotation if trace else None)
     if state["prof"] is not None:
@@ -444,7 +457,7 @@ def _run(name, cell, conf, traffic, m, seed, seconds, trace, t_start, dev,
             if name in x.get("workloads", [name])
             and vals[x["name"]] is not None}
     else:
-        reduced = _reduce_trace(log_dir)
+        reduced = _reduce_trace(log_dir, log)
         if reduced is not None:
             dev = dict(dev, busy_s=reduced["busy_s"],
                        window_s=reduced["window_s"])
@@ -482,17 +495,28 @@ def devices_stats(devices) -> dict:
     return best
 
 
-def _reduce_trace(log_dir: Path) -> dict | None:
-    """Device busy time, top ops and idle gaps of the traced seconds."""
+def _reduce_trace(log_dir: Path, log=print) -> dict | None:
+    """Device busy time, top ops and idle gaps of the traced seconds;
+    None, with the reason on stderr, where the trace has nothing to read."""
     from bench import trace_reduce as tr
+
+    def missing(why):
+        log(f"# trace: {why}; busy_s, window_s and the breakdown are left "
+            f"out", file=sys.stderr)
+
     try:
         path = tr.find_xplane(str(log_dir))
     except FileNotFoundError:
+        missing(f"no .xplane.pb under {log_dir}")
         return None
     data = tr.read_xplane(path)
     shutil.rmtree(log_dir, ignore_errors=True)     # traces are large
     ticks = [h for h in data["host"] if h[0] == "bench.tick"]
-    if not ticks or not data["devices"]:
+    if not ticks:
+        missing("no bench.tick in the traced seconds")
+        return None
+    if not data["devices"]:
+        missing("no TPU plane in the trace")
         return None
     lo, hi = ticks[0][1], ticks[-1][2]
     planes = sorted(data["devices"])

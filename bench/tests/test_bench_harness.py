@@ -1,9 +1,14 @@
 """The harness end to end at a tiny size on the CPU (the look for a chip
 skipped): a sound run is correct and reports its metrics; the float8
-control reads a gap above the limit."""
+control reads a gap above the limit; a closed loop never runs dry; a
+traced run says why a trace reading is missing."""
 import json
 
+import pytest
+
 import tiny
+from bench import harness, trace_reduce, workload
+from bench.weights import make_params
 
 E2E = {"ttft_p95_ms", "itl_p95_ms", "output_tokens_per_s", "setup_s"}
 
@@ -45,3 +50,50 @@ def test_too_few_compared_tokens_is_not_correct():
     assert c["logit_gap"]["value"] <= c["logit_gap"]["limit"]
     assert c["compared_tokens"]["value"] < c["compared_tokens"]["limit"]
     assert not r["correct"], c
+
+
+# a closed loop whose whole request set the CPU engine serves in a
+# fraction of a second
+DRAINED = {"loop": "closed", "requests": 4, "callers": 4, "lead_in_s": 0.2,
+           "levels": 4,
+           "prompt": {"median": 10, "sigma": 0.6, "min": 4, "max": 16},
+           "output": {"median": 6, "sigma": 0.5, "min": 2, "max": 12}}
+
+
+def test_drained_closed_loop_keeps_ticking():
+    conf = tiny.conf()
+    m = conf["model"]
+    engine = harness.build_engine(conf, harness.model_config(conf),
+                                  make_params(m, 7))
+    supply = workload.rounds(DRAINED, 7, m["vocab_size"])
+    specs = next(supply)
+    harness.warm(engine, harness.warm_plan(engine, specs), m["vocab_size"])
+    win = harness.drive(engine, specs, DRAINED, harness.TRACE_S + 2.0,
+                        more=supply, record=True)
+    n = DRAINED["requests"]
+    first = [r for r in win.recs if r.spec.idx < n]
+    assert len(first) == n and all(r.done is not None for r in first)
+    traced = win.w1 - harness.TRACE_S
+    assert max(r.done for r in first) < traced   # round 0 ran out early
+    assert any(traced <= k["ts"] < win.w1 for k in win.ticks)
+    assert any(n <= r.spec.idx < 2 * n for r in win.recs)   # round 1 sent
+    assert all(not r.refused for r in win.recs)
+
+
+@pytest.mark.parametrize("case,why", [
+    ("no_file", "no .xplane.pb"),
+    ("no_tick", "no bench.tick in the traced seconds"),
+    ("no_device", "no TPU plane"),
+])
+def test_missing_trace_reading_says_why(case, why, tmp_path, monkeypatch):
+    data = {"no_tick": {"devices": {"/device:TPU:0": [("op", 0, 5)]},
+                        "host": [("serve.tick", 0, 10)]},
+            "no_device": {"devices": {}, "host": [("bench.tick", 0, 10)]}}
+    if case != "no_file":
+        monkeypatch.setattr(trace_reduce, "find_xplane", lambda d: "x.pb")
+        monkeypatch.setattr(trace_reduce, "read_xplane", lambda p: data[case])
+    said = []
+    got = harness._reduce_trace(tmp_path / "trace",
+                                log=lambda *a, **k: said.append(a[0]))
+    assert got is None
+    assert len(said) == 1 and why in said[0]

@@ -4,8 +4,11 @@ A traffic file (``bench/traffic/<mix>.json``) holds parameters only:
 
   loop        "open" (arrivals on a schedule) or "closed" (callers that
               each send their next request when the last one completes)
-  requests    size of the request set; those not yet sent when the
-              window closes are never sent
+  requests    size of the request set (one round).  Open loop: those
+              not yet due when the window closes are never sent.  Closed
+              loop: once every request of a round has been sent, the
+              callers go on with the next round, for as long as the
+              window lasts (see "Rounds" below)
   rate_per_s  open loop: offered requests per second
   callers     closed loop: number of callers
   lead_in_s   seconds of the cell's own traffic before the window opens
@@ -23,11 +26,27 @@ harness, the weights).  With the few dozen requests a window holds at
 this system's speed, reordering them alone moved a tail by tens of
 percent from seed to seed; replaying one schedule keeps each seed's work
 the same, and fixes per cell the shapes the warm-up has to cover.
+
+Rounds.  A closed loop's supply must not depend on the program's speed:
+a program fast enough to send every request of the set would otherwise
+leave the chip idle for the rest of the window.  So ``rounds`` yields the
+set again and again.  Round 0 is ``generate``'s set.  Round r >= 1 has
+round 0's lengths, sessions and ``max_new`` in round 0's order; its own
+token ids are drawn from the same seed after round r-1's, so round 0 is
+the same whether or not later rounds are drawn, and unique prompts stay
+unique (no round hits the prefix cache on an earlier round's blocks).  A
+session's prefix is drawn once per run and is the same in every round.
+Each request's ``idx`` is ``r * requests + j``, distinct over all
+rounds.  Every round has round 0's lengths, so warming round 0's shapes
+warms them all.  An open loop's supply is its schedule: it has round 0
+alone.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import math
+from collections.abc import Iterator
 from dataclasses import dataclass
 from pathlib import Path
 from statistics import NormalDist
@@ -90,8 +109,13 @@ def _gaps(n: int, rate: float) -> np.ndarray:
 
 
 def generate(t: dict, seed: int, vocab: int) -> list[Spec]:
-    """The cell's requests for one run: the schedule is the same for
-    every seed, the token ids are drawn from ``seed``."""
+    """The cell's requests for one run (round 0): the schedule is the same
+    for every seed, the token ids are drawn from ``seed``."""
+    return next(rounds(t, seed, vocab))
+
+
+def rounds(t: dict, seed: int, vocab: int) -> Iterator[list[Spec]]:
+    """Round 0, then round 1, 2, ... of the request set, drawn lazily."""
     fixed = np.random.default_rng(0)         # the schedule, for every seed
     rng = np.random.default_rng(seed)        # this run's tokens
     n = int(t["requests"])
@@ -120,14 +144,17 @@ def generate(t: dict, seed: int, vocab: int) -> list[Spec]:
     else:
         due = [None] * n                     # a caller sends it when free
 
-    specs = []
-    for j, i in enumerate(fixed.permutation(n)):
-        body = rng.integers(0, vocab, int(own[i])).astype(np.int32)
-        r = sess[i]
-        if r is not None:
-            body = np.concatenate([prefixes[r], body])
-        specs.append(Spec(idx=j, due_s=None if due[j] is None
-                          else float(due[j]), session=r,
-                          prefix_len=rank_len[r] if r is not None else 0,
-                          prompt=body, max_new=int(new[i])))
-    return specs
+    order = fixed.permutation(n)
+    # an open loop's supply is its schedule; a closed loop never runs dry
+    for rnd in range(1) if t["loop"] == "open" else itertools.count():
+        specs = []
+        for j, i in enumerate(order):
+            body = rng.integers(0, vocab, int(own[i])).astype(np.int32)
+            r = sess[i]
+            if r is not None:
+                body = np.concatenate([prefixes[r], body])
+            specs.append(Spec(idx=rnd * n + j, due_s=None if due[j] is None
+                              else float(due[j]), session=r,
+                              prefix_len=rank_len[r] if r is not None else 0,
+                              prompt=body, max_new=int(new[i])))
+        yield specs
