@@ -113,11 +113,12 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, *, pos,
     it already applies to prefill), so a short batch attends over 2 table
     columns instead of all MB — the paged-attention savings with zero
     runtime control flow.  On TPU this dispatches to the Pallas kernel in
-    kernels/paged_attention, whose kv grid axis *is* the visible prefix
-    (online softmax streamed across blocks in VMEM — no dense gather at
-    all); the CPU fallback gathers the visible blocks and runs one fused
-    masked attention over them (numerics identical to the full-width
-    gather path: masked tails contribute exp(-inf) = 0).
+    kernels/paged_attention, whose chunk axis spans the visible prefix and
+    which copies only each request's live blocks (online softmax streamed
+    across chunks in VMEM — no dense gather at all); the CPU fallback
+    gathers the visible blocks and runs one fused masked attention over
+    them (numerics identical to the full-width gather path: masked tails
+    contribute exp(-inf) = 0).
     """
     if jax.default_backend() == "tpu":
         from repro.kernels.paged_attention import paged_attention_op

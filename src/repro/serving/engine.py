@@ -668,8 +668,12 @@ class ServingEngine:
                 tok = jnp.asarray(self.slot_tok[:, None])
                 pos = jnp.asarray(self.slot_pos)
                 cols = self._ctx_cols(int(self.slot_pos[active].max()))
-                with self.tr.span("serve.decode", batch=len(active),
-                                  cols=cols):
+                args = {"batch": len(active), "cols": cols}
+                if self.pool.kind == "paged":
+                    # blocks the paged kernel fetches: each live prefix
+                    args["kv_blocks"] = int(
+                        (self.slot_pos[active] // self.pool.bs + 1).sum())
+                with self.tr.span("serve.decode", **args):
                     t_dec = time.perf_counter()
                     logits, new_cache = self._decode_exec(cols)(
                         self.params, self.pool.decode_cache(), tok, pos)

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_config
+from repro.kernels.paged_attention import kernel as paged_kernel
 from repro.kernels.paged_attention import paged_attention, paged_attention_ref
 from repro.kernels.quant import dequantize_ref, quantize_ref
 from repro.models import lm
@@ -45,23 +46,78 @@ def _gather_path(q, kp, vp, bt, pos):
     return decode_attention(q, kg, vg, pos=pos)
 
 
-@pytest.mark.parametrize("B,S,H,K,hd,bs,MB", [
-    (2, 1, 4, 2, 16, 8, 6),      # single-token decode, GQA
-    (4, 1, 4, 4, 32, 16, 4),     # MHA-style, bigger blocks
-    (1, 1, 8, 2, 64, 8, 12),     # deep table
-    (3, 5, 4, 2, 16, 8, 6),      # multi-token chunked decode
-    (2, 7, 6, 2, 32, 16, 6),     # chunk not dividing block size
+def _kernel_case(B, S, H, K, hd, bs, MB, ctx=0, pos=None, *, id=None):
+    return pytest.param(B, S, H, K, hd, bs, MB, ctx, pos,
+                        id=id or f"{B}-{S}-{H}-{K}-{hd}-{bs}-{MB}")
+
+
+@pytest.mark.parametrize("B,S,H,K,hd,bs,MB,ctx,pos", [
+    _kernel_case(2, 1, 4, 2, 16, 8, 6),      # single-token decode, GQA
+    _kernel_case(4, 1, 4, 4, 32, 16, 4),     # MHA-style, bigger blocks
+    _kernel_case(1, 1, 8, 2, 64, 8, 12),     # deep table
+    _kernel_case(3, 5, 4, 2, 16, 8, 6),      # multi-token chunked decode
+    _kernel_case(2, 7, 6, 2, 32, 16, 6),     # chunk not dividing block size
+    # the cells' head geometry: starcoder2-3b (G 12) and phi4-mini (G 3).
+    # 40 visible blocks are a 32-block chunk and a partial one; a free
+    # slot (pos 0) sits beside long ones
+    _kernel_case(3, 1, 24, 2, 128, 16, 40, 0, [0, 600, 631],
+                 id="sc2-decode-free-slot"),
+    _kernel_case(3, 1, 24, 8, 128, 16, 40, 36, [0, 560, 17],
+                 id="phi4-decode-ctx-below-table"),
+    _kernel_case(2, 4, 24, 2, 128, 16, 40, 0, [0, 590],
+                 id="sc2-multi"),
+    _kernel_case(2, 3, 24, 8, 128, 16, 40, 38, [601, 0],
+                 id="phi4-verify-ctx-below-table"),
+    # S*G above one query tile: the query-chunk axis (G 12: 576 rows;
+    # G 3: 600 rows, a tile edge inside one query's head group)
+    _kernel_case(1, 48, 24, 2, 128, 16, 8, 0, [40], id="sc2-multi-two-tiles"),
+    _kernel_case(1, 200, 24, 8, 128, 16, 20, 0, [100],
+                 id="phi4-multi-two-tiles"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_kernel_matches_ref(B, S, H, K, hd, bs, MB, dtype):
-    q, kp, vp, bt, pos = _case(B, S, H, K, hd, bs, MB)
+def test_kernel_matches_ref(B, S, H, K, hd, bs, MB, ctx, pos, dtype):
+    q, kp, vp, bt, pos = _case(B, S, H, K, hd, bs, MB, pos=pos)
     q, kp, vp = q.astype(dtype), kp.astype(dtype), vp.astype(dtype)
-    out = paged_attention(q, kp, vp, bt, pos, interpret=True)
+    out = paged_attention(q, kp, vp, bt, pos, ctx_cols=ctx, interpret=True)
     ref = paged_attention_ref(q, kp, vp, bt, pos)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("S,K,pos", [
+    (1, 2, [0, 37, 70]),          # decode: free slot, chunk edge, partial
+    (5, 8, [12, 0, 51]),          # multi-token, phi4's head group
+    (48, 2, [3, 20, 0]),          # two query tiles
+])
+def test_kernel_reads_only_live_blocks(S, K, pos, monkeypatch):
+    """Every physical block outside each slot's live table prefix (the
+    blocks holding positions < pos + S) is NaN in K and V, the trash
+    block too: the kernel must neither fetch nor use them, and must not
+    let a partly copied chunk's stale buffer rows through.  Its output
+    equals the oracle's on clean pools."""
+    B, H, hd, bs, MB = 3, 24, 128, 8, 12
+    q, kp, vp, _, pos = _case(B, S, H, K, hd, bs, MB, pos=pos)
+    # private blocks 1.. per slot; columns past the live prefix -> trash 0
+    bt = np.zeros((B, MB), np.int32)
+    live = set()
+    for b in range(B):
+        n = (int(pos[b]) + S - 1) // bs + 1
+        bt[b, :n] = 1 + b * MB + np.arange(n)
+        live.update(bt[b, :n].tolist())
+    bt = jnp.asarray(bt)
+    dead = np.array([i not in live for i in range(kp.shape[0])])
+    nan = jnp.asarray(dead)[:, None, None, None]
+    kn = jnp.where(nan, jnp.nan, kp)
+    vn = jnp.where(nan, jnp.nan, vp)
+    ref = paged_attention_ref(q, kp, vp, bt, pos)
+    # a chunk of 4 blocks: 12 visible columns are three chunks
+    monkeypatch.setattr(paged_kernel, "_CHUNK_TOKENS", 4 * bs)
+    out = paged_attention(q, kn, vn, bt, pos, interpret=True)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
 
 
 def test_tail_block_lengths():

@@ -243,6 +243,23 @@ def test_failed_background_build_is_counted(model):
     assert all(len(r.tokens_out) == 4 for r in reqs)
 
 
+def test_decode_span_counts_live_kv_blocks(model):
+    """``serve.decode`` carries ``kv_blocks``: the blocks the paged kernel
+    fetches, each live slot's prefix ``pos // block_size + 1``."""
+    cfg, params = model
+    eng = ServingEngine(params, cfg, _setting(max_batch=3, block_size=16),
+                        max_seq=48)
+    tr = Tracer()
+    eng.set_tracer(tr)
+    reqs = _requests(cfg, [20, 9], max_new=3)
+    serve_loop(eng, reqs)
+    # both admitted in the first step, decoding at 20/9, 21/10: 2 + 1
+    # blocks; each stops after 2 decodes (its prefill made the first token)
+    got = [(e["args"]["batch"], e["args"]["kv_blocks"])
+           for e in tr.events if e["name"] == "serve.decode"]
+    assert got == [(2, 3), (2, 3)]
+
+
 def test_tick_counts_and_request_stamps(model):
     """``serve.tick`` carries the step's queue and admission counts, equal
     to what the engine did; each request's stamps are ordered, and its

@@ -1,10 +1,12 @@
 """Compile rehearsal: the paged-attention kernel compiled for a described
-TPU v5e chip at starcoder2-3b's widths (24 query heads, 2 KV heads,
-head_dim 128), for the decode (S=1) and spec_k=2 verify (S=3) widths,
-both pool dtypes and both block sizes, and one engine decode step at
-those widths (one layer).  Nothing runs: the TPU compiler refuses here
-what it would refuse on the chip (tile alignment, VMEM), at no chip time;
-and the compiled text shows the names a profiler trace will carry.  The
+TPU v5e chip at the benchmark cells' head geometries (24 query heads of
+128 over 2 KV heads, starcoder2-3b; over 8, phi4-mini), for the decode
+(S=1) and spec_k=2 verify (S=3) widths, both pool dtypes and both block
+sizes, and a suffix prefill wide enough for two query tiles; and one
+engine decode step at starcoder2-3b's widths (one layer).  Nothing runs:
+the TPU compiler refuses here what it would refuse on the chip (tile
+alignment, VMEM), at no chip time; and the compiled text shows the names
+a profiler trace will carry.  The
 topology is described inside a fixture, never at import, so every xdist
 worker collects the same tests and only the one running this file loads
 the TPU compiler."""
@@ -22,7 +24,7 @@ from repro.models import lm
 from repro.models.lm import ModelKnobs
 from repro.serving.engine import decode_fn
 
-B, H, K, HD, MB = 8, 24, 2, 128, 64       # starcoder2-3b, max_seq 1024/16
+B, H, HD, MB = 8, 24, 128, 64      # 24 query heads of 128; max_seq 1024/16
 
 
 @pytest.fixture(scope="module")
@@ -45,10 +47,22 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("bs", [8, 16])
-@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.float32])
-@pytest.mark.parametrize("S", [1, 3])
-def test_paged_attention_compiles_for_v5e(one_chip, S, pool_dtype, bs):
+def _compile_cases():
+    """starcoder2-3b's 24/2 heads (ids as before) and phi4-mini's 24/8, at
+    the decode and spec_k=2 verify widths, both pool dtypes and block
+    sizes; and a suffix prefill whose S*G rows span two query tiles."""
+    for K, tag in ((2, ""), (8, "phi4-")):
+        for S in (1, 3):
+            for dt in (jnp.bfloat16, jnp.float32):
+                for bs in (8, 16):
+                    yield pytest.param(S, K, dt, bs,
+                                       id=f"{tag}{S}-{dt.__name__}-{bs}")
+    for K, S in ((2, 64), (8, 256)):
+        yield pytest.param(S, K, jnp.bfloat16, 16, id=f"K{K}-multi-{S}")
+
+
+@pytest.mark.parametrize("S,K,pool_dtype,bs", _compile_cases())
+def test_paged_attention_compiles_for_v5e(one_chip, S, K, pool_dtype, bs):
     NB = B * MB + 1
 
     def sds(shape, dtype):
