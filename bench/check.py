@@ -2,9 +2,10 @@
 
 After the window, a sample drawn from the seed of the requests that
 finished in it (the longest always among them, then seeded picks until
-``check.tokens`` served tokens, 256 at the cells' sizes) is run through
-the reference once, prompt and served tokens together, packed side by
-side into rows of ``check.pack`` tokens (one compiled shape).  For each
+``check.tokens`` served tokens, 256 at the cells' sizes) is run once
+through the f32 reference of the configuration's block (its
+``logits_at``), prompt and served tokens together, packed side by side
+into rows of ``check.pack`` tokens (one compiled shape).  For each
 served token the number compared is how far its reference logit lies
 below the reference's best at that position: 0 where the program chose
 the reference's argmax, small where bf16 rounding flipped a near tie,
@@ -13,11 +14,11 @@ over the sample is held to the configuration's limit (``check.logit_gap``
 in its file; how the limit was set is in PERF.md), and the sample has to
 hold at least ``check.tokens`` served tokens.
 
-The control (``control=True``) puts the reference computed in float8 in
-the program's place: at each position of the same prompts and served
-tokens, the token that float8 puts first goes through the same gap and
-limit, so a sound control run comes out not correct.  The program's own
-gap is printed beside it.
+The control (``control=True``) puts the block's reference computed in
+float8 in the program's place: at each position of the same prompts and
+served tokens, the token that float8 puts first goes through the same
+gap and limit, so a sound control run comes out not correct.  The
+program's own gap is printed beside it.
 """
 from __future__ import annotations
 
@@ -25,8 +26,6 @@ import sys
 import time
 
 import numpy as np
-
-from bench import reference
 
 
 def finished(win) -> list:
@@ -92,23 +91,25 @@ def inputs(pick: list):
     return seqs, rows, toks
 
 
-def gaps(m: dict, seed: int, pick: list, pack: int, control: bool):
+def gaps(block, m: dict, seed: int, pick: list, pack: int,
+         control: bool):
     """Widest gap of the served tokens, and of the control's tokens."""
     gap = cgap = 0.0
     for row in rows_of(pick, pack):
         seqs, rows, toks = inputs(row)
-        ref = reference.logits_at(m, seed, seqs, rows, pack)
+        ref = block.logits_at(m, seed, seqs, rows, pack)
         gap = max([gap] + [widest_gap(r, t) for r, t in zip(ref, toks)])
         if control:
-            low = reference.logits_at(m, seed, seqs, rows, pack,
-                                      precision="fp8")
+            low = block.logits_at(m, seed, seqs, rows, pack,
+                                  precision="fp8")
             cgap = max([cgap] + [widest_gap(r, lo.argmax(-1))
                                  for r, lo in zip(ref, low)])
     return gap, cgap
 
 
-def check(conf: dict, seed: int, win, *, control: bool = False,
+def check(conf: dict, block, seed: int, win, *, control: bool = False,
           log=print) -> dict:
+    """The verdict on ``win``; ``block`` is ``blocks.of(conf)``."""
     m = conf["model"]
     V = m["vocab_size"]
     done = finished(win)
@@ -126,7 +127,7 @@ def check(conf: dict, seed: int, win, *, control: bool = False,
     gap = None
     if pick:
         t0 = time.perf_counter()
-        gap, cgap = gaps(m, seed, pick, pack, control)
+        gap, cgap = gaps(block, m, seed, pick, pack, control)
         log(f"# reference: {len(pick)} requests, {n} tokens, "
             f"{len(rows_of(pick, pack))} rows, "
             f"{time.perf_counter() - t0:.3f} s", file=sys.stderr)

@@ -2,13 +2,15 @@
 
 Everything a cell is made of is found by name: the cell in
 ``BENCHMARK.json``, its configuration in ``bench/configs/<config>.json``,
-its traffic in ``bench/traffic/<traffic>.json`` and each per-layer
-metric's reader in ``bench/layer_metrics/<metric>.py``.  Adding a cell,
-a configuration, a mix or a metric adds files; this one stays as it is.
+its traffic in ``bench/traffic/<traffic>.json``, the block it names in
+``bench/blocks/<block>.py`` (weights, f32 reference and work counts) and
+each per-layer metric's reader in ``bench/layer_metrics/<metric>.py``.
+Adding a cell, a configuration, a mix, a metric or a block adds files;
+this one stays as it is.
 
 A run:
-  1. makes bf16 weights on the chip from the seed and builds the
-     program's ``ServingEngine`` with the configuration's setting;
+  1. makes the block's bf16 weights on the chip from the seed and builds
+     the program's ``ServingEngine`` with the configuration's setting;
   2. warms every shape the seeded traffic can reach, then serves the
      traffic's lead-in, so the window starts warm and busy;
   3. serves the window, stamping every token with the host clock after
@@ -17,7 +19,8 @@ A run:
      (``--trace 1``: spans, counters and a profiler trace of the window's
      last seconds);
   5. frees the program and compares a seeded sample of the requests that
-     finished in the window with the f32 reference (``bench.check``).
+     finished in the window with the block's f32 reference
+     (``bench.check``).
 """
 from __future__ import annotations
 
@@ -34,7 +37,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import check, workload
+from bench import blocks, check, workload
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
@@ -325,6 +328,7 @@ def e2e(win: Window) -> dict:
 class RunRecord:
     """What a per-layer metric's reader may read."""
     model: dict
+    block: object          # the configuration's block module (work counts)
     peaks: dict
     window: Window
     spans: list            # tracer events in the window, absolute "t0"
@@ -359,27 +363,27 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     peaks = peaks_for(dev["kind"]) if require_tpu else peaks
     conf = conf or load_config(cell["config"])
     traffic = traffic or workload.load_traffic(cell["traffic"])
+    block = blocks.of(conf)
     m = conf["model"]
     compiles = CompileCounter()
     try:
-        return _run(name, cell, conf, traffic, m, seed, seconds, trace,
-                    t_start, dev, peaks, compiles, program_hook, control,
-                    bench, log)
+        return _run(name, cell, conf, block, traffic, m, seed, seconds,
+                    trace, t_start, dev, peaks, compiles, program_hook,
+                    control, bench, log)
     finally:
         compiles.close()
 
 
-def _run(name, cell, conf, traffic, m, seed, seconds, trace, t_start, dev,
-         peaks, compiles, program_hook, control, bench, log):
+def _run(name, cell, conf, block, traffic, m, seed, seconds, trace,
+         t_start, dev, peaks, compiles, program_hook, control, bench, log):
     import jax
-    from bench.weights import make_params
     from repro.obs.trace import Tracer
 
     cfg = model_config(conf)
     supply = workload.rounds(traffic, seed, m["vocab_size"])
     specs = next(supply)                  # round 0 fixes the warmed shapes
     t_init = time.perf_counter()
-    params = jax.block_until_ready(make_params(m, seed))
+    params = jax.block_until_ready(block.make_params(m, seed))
     t_params = time.perf_counter()
     engine = build_engine(conf, cfg, params)
     plan = warm_plan(engine, specs)
@@ -439,7 +443,7 @@ def _run(name, cell, conf, traffic, m, seed, seconds, trace, t_start, dev,
     del engine, params
     gc.collect()
 
-    verdict = check.check(conf, seed, win, control=control, log=log)
+    verdict = check.check(conf, block, seed, win, control=control, log=log)
 
     e = e2e(win)
     due = [r for r in win.recs if win.w0 <= r.due < win.w1]
@@ -461,8 +465,9 @@ def _run(name, cell, conf, traffic, m, seed, seconds, trace, t_start, dev,
         if reduced is not None:
             dev = dict(dev, busy_s=reduced["busy_s"],
                        window_s=reduced["window_s"])
-        rec = RunRecord(model=dict(conf["model"]), peaks=peaks,
-                        window=win, spans=spans, counters=counters,
+        rec = RunRecord(model=dict(conf["model"]), block=block,
+                        peaks=peaks, window=win, spans=spans,
+                        counters=counters,
                         compiles=n_compiles, peak_bytes=peak, trace=reduced)
         metrics = {}
         for pm in bench["per_layer"]:

@@ -38,8 +38,7 @@ def main() -> int:
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    from bench import harness, workload
-    from bench.weights import make_params
+    from bench import blocks, harness, workload
 
     if jax.devices()[0].platform != "tpu":
         print("sweep: no TPU; nothing run", file=sys.stderr)
@@ -55,7 +54,7 @@ def main() -> int:
         t = dict(base, rate_per_s=r)
         plans.append((r, t, workload.generate(t, args.seed + i,
                                               m["vocab_size"])))
-    params = make_params(m, args.seed)
+    params = blocks.of(conf).make_params(m, args.seed)
     engine = harness.build_engine(conf, harness.model_config(conf), params)
     every = [s for _, _, specs in plans for s in specs]
     harness.warm(engine, harness.warm_plan(engine, every), m["vocab_size"])

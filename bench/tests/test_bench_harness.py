@@ -7,8 +7,7 @@ import json
 import pytest
 
 import tiny
-from bench import harness, trace_reduce, workload
-from bench.weights import make_params
+from bench import blocks, harness, trace_reduce, workload
 
 E2E = {"ttft_p95_ms", "itl_p95_ms", "output_tokens_per_s", "setup_s"}
 
@@ -64,7 +63,7 @@ def test_drained_closed_loop_keeps_ticking():
     conf = tiny.conf()
     m = conf["model"]
     engine = harness.build_engine(conf, harness.model_config(conf),
-                                  make_params(m, 7))
+                                  blocks.of(conf).make_params(m, 7))
     supply = workload.rounds(DRAINED, 7, m["vocab_size"])
     specs = next(supply)
     harness.warm(engine, harness.warm_plan(engine, specs), m["vocab_size"])
